@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.core.network import deliver_remote_ref
 from repro.kernels import ops, ref
 
 PEAK = 197e12
@@ -55,14 +56,14 @@ def main():
     want = jref(spikes[:4, :256], w[:4, :256, :256])
     assert jnp.allclose(got, want, atol=1e-4), "pallas mismatch"
 
-    # ell_gather at paper shape
+    # remote ELL gather (the XLA gather every impl uses) at paper shape
     kk = 248
     o = 20
     t_tbl = o * n
     s = (jax.random.uniform(k1, (c, t_tbl)) < 0.005).astype(jnp.float32)
     idx = jax.random.randint(k2, (c, n, kk), 0, t_tbl)
     wr = jax.random.normal(k1, (c, n, kk))
-    jref2 = jax.jit(ref.ell_gather_ref)
+    jref2 = jax.jit(deliver_remote_ref)
     t = bench(jref2, s, idx, wr)
     bytes_moved = c * n * kk * (4 + 4 + 4)
     print(f"ell_gather_ref_cpu,{t*1e6:.0f},"

@@ -249,9 +249,10 @@ def restore(ckpt_dir: str, tree_like: Any, step: Optional[int] = None,
 #          rings are bitwise what a run on the new mesh would hold — stale
 #          ring buffers are never copied across meshes.
 #   step counter  (S,)                   t — equal on every shard; verified
-#   global sums   (S,)                   spike/event counts + ISI moments —
+#   global sums   (S[, 2])               spike/event counts (int32
+#       core/counters.py pairs) + ISI moments (integer-valued f32) —
 #       partial per-shard sums whose psum is the observable; the total
-#       moves to shard 0 (integer-valued f32: exact, order-independent)
+#       moves to shard 0 (exact, order-independent)
 #   per-step flag (S,)                   aer_sat — write-only scan output,
 #       reset to False for the new mesh
 
@@ -312,8 +313,10 @@ def _reshard_leaf(name: str, x, from_spec, to_spec):
                 f"clean post-step snapshot")
         return np.full((s_new,), x.flat[0], x.dtype)
     if name in _SUM_LEAVES:
-        out = np.zeros((s_new,), x.dtype)
-        out[0] = x.sum(dtype=np.float64).astype(x.dtype)
+        # f32 ISI moments, or core/counters.py [hi, lo] int32 pairs
+        # (summed word by word; the next add or value() carries lo)
+        out = np.zeros((s_new,) + x.shape[1:], x.dtype)
+        out[0] = x.sum(axis=0, dtype=np.float64).astype(x.dtype)
         return out
     if name == "aer_sat":
         return np.zeros((s_new,), x.dtype)

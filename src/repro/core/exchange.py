@@ -83,6 +83,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import DPSNNConfig
 from repro.core import connectivity as conn
+from repro.core import counters
 from repro.core import network as net
 from repro.core import plasticity as plast
 from repro.core.connectivity import StencilSpec, build_stencil
@@ -92,25 +93,6 @@ from repro.core.partition import TileSpec, tile_column_ids
 from repro.core.plasticity import STDPState
 from repro.runtime import integrity
 from repro.runtime.integrity import GuardState
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma across
-# jax versions; resolve whichever this jax spells
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map_impl).parameters
-             else "check_rep")
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check_vma})
-
 
 # ---------------------------------------------------------------------------
 # Spike bit-packing (dense_packed halo payloads)
@@ -231,15 +213,6 @@ def aer_scatter_values(events: jax.Array, values: jax.Array, shape: tuple
 # Halo exchange
 # ---------------------------------------------------------------------------
 
-def _axis_size(axis_name) -> int:
-    """Static size of a (possibly tuple) mesh axis inside shard_map.
-    jax >= 0.6 spells this jax.lax.axis_size; older versions constant-fold
-    psum of a Python int to the same value."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def assert_axis_sizes(spec: TileSpec, row_axes, col_axis) -> None:
     """Trace-time guard: the mesh axes this step runs over must match the
     TileSpec's shard grid. Runs inside shard_map (sizes are static), so a
@@ -247,7 +220,7 @@ def assert_axis_sizes(spec: TileSpec, row_axes, col_axis) -> None:
     count disagrees with the tile decomposition — fails at trace time
     with the two geometries named, instead of silently exchanging halos
     with the wrong neighbours."""
-    rows, cols = _axis_size(row_axes), _axis_size(col_axis)
+    rows, cols = jax.lax.axis_size(row_axes), jax.lax.axis_size(col_axis)
     if (rows, cols) != (spec.tiles_y, spec.tiles_x):
         raise ValueError(
             f"mesh axes {rows}x{cols} (row_axes={row_axes!r}, "
@@ -261,7 +234,7 @@ def assert_axis_sizes(spec: TileSpec, row_axes, col_axis) -> None:
 def _shift(x: jax.Array, axis_name, direction: int) -> jax.Array:
     """ppermute by +-1 along (possibly tuple) mesh axis. Shards at the open
     boundary receive zeros (the cortical sheet edge, paper Sec. 2)."""
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     if size == 1:
         return jnp.zeros_like(x)
     if direction > 0:      # receive from my +1 neighbour (they send to -1)
@@ -626,7 +599,7 @@ def exchange_halo_hier(frame: jax.Array, spec: TileSpec, node, *,
     dtype = frame.dtype
     gy, gx = node.group_h, node.group_w
     ny, nx = node.nodes_y, node.nodes_x
-    sizes = tuple(_axis_size(a) for a in HIER_AXES)
+    sizes = tuple(jax.lax.axis_size(a) for a in HIER_AXES)
     if sizes != (ny, gy, nx, gx):
         raise ValueError(
             f"hierarchical mesh axes {HIER_AXES} have sizes {sizes}, "
@@ -830,8 +803,8 @@ def init_shard(cfg: DPSNNConfig, spec: TileSpec, stencil: StencilSpec,
                            dtype),
         pending=jnp.zeros((spec.tile_h, spec.tile_w, n), dtype),
         t=jnp.int32(0),
-        spike_count=jnp.float32(0),
-        event_count=jnp.float32(0),
+        spike_count=counters.zero(),
+        event_count=counters.zero(),
         plastic=plastic,
         aer_sat=jnp.zeros((), jnp.bool_),
         # zero in-flight frame == the empty pre-t=0 history, so the
@@ -1102,12 +1075,11 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
         hist_ext = jax.lax.dynamic_update_index_in_dim(
             state.hist_ext, ext_frame, (state.t - 1) % d_slots, axis=0)
 
+    # exact int32 counts, as in network.step_single (core/counters.py)
     k_tot = params.rem_w.shape[-1]
-    events = (
-        (s_loc * 0.0).sum()  # keep dtype promotion simple
-        + (spikes * (params.local_outdeg + k_tot)).sum()
-        + ext_counts.sum().astype(jnp.float32)
-    )
+    n_spikes = spikes.astype(jnp.int32)
+    events = ((n_spikes * (params.local_outdeg.astype(jnp.int32) + k_tot)
+               ).sum() + ext_counts.sum())
 
     # (5) ISI accumulation: a neuron spiking at t with a recorded previous
     # spike contributes isi = t - last_spike_t. Sums are integer-valued
@@ -1142,8 +1114,8 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
         hist_ext=hist_ext,
         pending=spikes.reshape(spec.tile_h, spec.tile_w, n),
         t=state.t + 1,
-        spike_count=state.spike_count + spikes.sum(),
-        event_count=state.event_count + events,
+        spike_count=counters.add(state.spike_count, n_spikes.sum()),
+        event_count=counters.add(state.event_count, events),
         plastic=new_plastic,
         aer_sat=aer_sat,
         ext_pending=new_ext_pending,
@@ -1217,8 +1189,8 @@ def make_distributed_run(cfg: DPSNNConfig, mesh: Mesh, *, n_steps: int,
             return s1, s1.aer_sat
 
         final, sat_steps = jax.lax.scan(body, state, None, length=n_steps)
-        spikes = jax.lax.psum(final.spike_count, joint)
-        events = jax.lax.psum(final.event_count, joint)
+        spikes = counters.value(jax.lax.psum(final.spike_count, joint))
+        events = counters.value(jax.lax.psum(final.event_count, joint))
         sim_s = n_steps * cfg.neuron.dt_ms * 1e-3
         rate = spikes / (cfg.n_neurons * sim_s)
         checksum = jax.lax.psum(final.lif.v.sum(), joint)
@@ -1249,8 +1221,8 @@ def make_distributed_run(cfg: DPSNNConfig, mesh: Mesh, *, n_steps: int,
     else:
         out_specs = result_specs
 
-    fn = _shard_map(fresh, mesh=mesh, in_specs=(), out_specs=out_specs,
-                    check_vma=False)
+    fn = jax.shard_map(fresh, mesh=mesh, in_specs=(), out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(fn), spec
 
 
@@ -1291,8 +1263,8 @@ def make_distributed_resume(cfg: DPSNNConfig, mesh: Mesh, *, n_steps: int,
             return s1, s1.aer_sat
 
         final, sat_steps = jax.lax.scan(body, state, None, length=n_steps)
-        spikes = jax.lax.psum(final.spike_count, joint)
-        events = jax.lax.psum(final.event_count, joint)
+        spikes = counters.value(jax.lax.psum(final.spike_count, joint))
+        events = counters.value(jax.lax.psum(final.event_count, joint))
         sim_s = n_steps * cfg.neuron.dt_ms * 1e-3
         rate = spikes / (cfg.n_neurons * sim_s)
         checksum = jax.lax.psum(final.lif.v.sum(), joint)
@@ -1310,9 +1282,9 @@ def make_distributed_resume(cfg: DPSNNConfig, mesh: Mesh, *, n_steps: int,
         specs = jax.tree_util.tree_map(lambda _: P(), struct)
     else:
         specs = _stack_specs(struct, joint)
-    fn = _shard_map(resume, mesh=mesh, in_specs=(specs,),
-                    out_specs=(DistResult(P(), P(), P(), P(), P()), specs),
-                    check_vma=False)
+    fn = jax.shard_map(resume, mesh=mesh, in_specs=(specs,),
+                       out_specs=(DistResult(P(), P(), P(), P(), P()), specs),
+                       check_vma=False)
     return jax.jit(fn), spec
 
 
@@ -1394,8 +1366,9 @@ def make_batched_distributed_run(cfg: DPSNNConfig, mesh: Mesh, *,
             return s1, s1.aer_sat                  # (b_local,) per step
 
         final, sat_steps = jax.lax.scan(body, state, None, length=n_steps)
-        spikes = jax.lax.psum(final.spike_count, spatial)     # (b_local,)
-        events = jax.lax.psum(final.event_count, spatial)
+        spikes = counters.value(
+            jax.lax.psum(final.spike_count, spatial))         # (b_local,)
+        events = counters.value(jax.lax.psum(final.event_count, spatial))
         sim_s = n_steps * cfg.neuron.dt_ms * 1e-3
         rate = spikes / (cfg.n_neurons * sim_s)
         checksum = jax.lax.psum(final.lif.v.sum(axis=(1, 2)), spatial)
@@ -1422,12 +1395,12 @@ def make_batched_distributed_run(cfg: DPSNNConfig, mesh: Mesh, *,
     else:
         out_specs = result_specs
     if not with_stimulus:
-        fn = _shard_map(lambda seeds: simulate(seeds, None), mesh=mesh,
-                        in_specs=in_specs, out_specs=out_specs,
-                        check_vma=False)
+        fn = jax.shard_map(lambda seeds: simulate(seeds, None), mesh=mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
     else:
-        fn = _shard_map(simulate, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(simulate, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(fn), spec
 
 

@@ -10,9 +10,12 @@ The network is a grid of columns. Per shard we hold:
   the TPU-native replacement for DPSNN's per-synapse delayed delivery
   queues (DESIGN.md §2).
 
-Delivery has two interchangeable implementations selected by ``impl``:
-``"ref"`` (pure jnp, the oracle) and ``"pallas"`` (kernels/). Both produce
-identical currents (tests/test_kernels.py asserts allclose).
+Local delivery has interchangeable implementations selected by ``impl``:
+``"ref"`` (pure jnp, the oracle), ``"pallas"`` (kernels/synapse_matmul)
+and ``"pallas_fused"`` (the column-step megakernel). They produce the same
+currents (tests/test_kernels.py and tests/test_fused_step.py assert it).
+Remote ELL delivery is the reference's XLA gather on every path: Mosaic
+has no in-kernel vector gather from a VMEM table row.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import DPSNNConfig
 from repro.core import connectivity as conn
+from repro.core import counters
 from repro.core.connectivity import StencilSpec, build_stencil
 from repro.core.neuron import LIFState, lif_init, lif_sfa_step
 
@@ -38,8 +42,8 @@ class NetworkState(NamedTuple):
     lif: LIFState           # leaves (C, N)
     hist: jax.Array         # (D, C, N) spike history ring buffer
     t: jax.Array            # scalar int32 step counter
-    spike_count: jax.Array  # scalar f32, total spikes emitted
-    event_count: jax.Array  # scalar f32, total synaptic events (paper metric)
+    spike_count: jax.Array  # counters.py pair: total spikes emitted
+    event_count: jax.Array  # counters.py pair: total synaptic events
     stdp: Optional[Any] = None  # STDPState traces when cfg.stdp, else None
     guard: Optional[Any] = None  # GuardState when cfg.guard.enabled
 
@@ -92,8 +96,8 @@ def init_state(cfg: DPSNNConfig, col_ids: jax.Array,
         lif=jax.vmap(col_init)(col_ids),
         hist=jnp.zeros((d, n_columns, n), dtype),
         t=jnp.int32(0),
-        spike_count=jnp.float32(0),
-        event_count=jnp.float32(0),
+        spike_count=counters.zero(),
+        event_count=counters.zero(),
         stdp=stdp,
         guard=guard,
     )
@@ -104,9 +108,14 @@ def init_state(cfg: DPSNNConfig, col_ids: jax.Array,
 # ---------------------------------------------------------------------------
 
 def deliver_local_ref(spikes: jax.Array, w_local: jax.Array) -> jax.Array:
-    """(C,N) x (C,N,N) -> (C,N): batched MXU matmul over columns."""
+    """(C,N) x (C,N,N) -> (C,N): batched MXU matmul over columns.
+
+    ``Precision.HIGHEST`` keeps the reference f32 on the chip too (a TPU
+    multiplies f32 operands in one bf16 pass at default precision); on
+    the CPU it changes nothing."""
     return jnp.einsum(
         "cs,cst->ct", spikes, w_local,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(spikes.dtype)
 
@@ -132,7 +141,7 @@ def _delivery_fns(impl: str):
         return deliver_local_ref, deliver_remote_ref
     if impl == "pallas":
         from repro.kernels import ops
-        return ops.synapse_matmul, ops.ell_gather
+        return ops.synapse_matmul, deliver_remote_ref
     raise ValueError(
         f"unknown delivery impl {impl!r} (expected 'ref' or 'pallas'; "
         f"'pallas_fused' runs the whole step as one megakernel and is "
@@ -282,18 +291,18 @@ def step_single(cfg: DPSNNConfig, params: NetworkParams,
     #    every emitted spike is delivered to its realized local out-degree
     #    plus (statistically exact for ELL) K_tot remote targets; external
     #    events count each Poisson arrival.
+    #    Counted in int32 (exact) into the counters.py running totals.
     k_tot = params.rem_w.shape[-1]
-    events = (
-        (spikes * (params.local_outdeg + k_tot)).sum()
-        + ext_counts.sum().astype(jnp.float32)
-    )
+    n_spikes = spikes.astype(jnp.int32)
+    events = ((n_spikes * (params.local_outdeg.astype(jnp.int32) + k_tot)
+               ).sum() + ext_counts.sum())
 
     return NetworkState(
         lif=lif,
         hist=hist,
         t=state.t + 1,
-        spike_count=state.spike_count + spikes.sum(),
-        event_count=state.event_count + events,
+        spike_count=counters.add(state.spike_count, n_spikes.sum()),
+        event_count=counters.add(state.event_count, events),
         # unfused: traces advance in the caller (simulation.run);
         # fused: the kernel already advanced them (caller consumes)
         stdp=new_stdp,
@@ -314,10 +323,11 @@ def fused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
     from repro.kernels import ops
     gcfg = cfg.guard if cfg.guard.enabled else None
     gflags = None
+    rem = deliver_remote_ref(s_flat, params.rem_flat, params.rem_w)
     if cfg.stdp:
         out = ops.fused_step(
             cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
-            params.w_local, s_flat, params.rem_flat, params.rem_w, ext,
+            params.w_local, rem, ext,
             stdp0.x_pre, stdp0.x_post, scfg=cfg.stdp_cfg, gcfg=gcfg)
         v, c, refrac, spikes, x_pre, x_post = out[:6]
         if gcfg is not None:
@@ -326,8 +336,7 @@ def fused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
     else:
         out = ops.fused_step(
             cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
-            params.w_local, s_flat, params.rem_flat, params.rem_w, ext,
-            gcfg=gcfg)
+            params.w_local, rem, ext, gcfg=gcfg)
         v, c, refrac, spikes = out[:4]
         if gcfg is not None:
             gflags = out[4]

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from typing import Optional
 
 from repro.configs.base import DPSNNConfig
+from repro.core import counters
 from repro.core import network as net
 from repro.core import plasticity as plast
 from repro.core.connectivity import build_stencil, neuron_types
@@ -84,7 +85,8 @@ def run(cfg: DPSNNConfig, params: NetworkParams, state: NetworkState,
                 new_traces=s1.stdp if fused else None,
             )
             s1 = s1._replace(stdp=traces)
-        step_rate = (s1.spike_count - s0.spike_count) / (
+        step_spikes = jnp.take(s1.hist, s0.t % s0.hist.shape[0], axis=0)
+        step_rate = step_spikes.sum() / (
             s0.hist.shape[1] * s0.hist.shape[2]
         ) / (cfg.neuron.dt_ms * 1e-3)
         return (p1, s1), step_rate
@@ -93,12 +95,12 @@ def run(cfg: DPSNNConfig, params: NetworkParams, state: NetworkState,
         body, (params, state), None, length=n_steps)
     sim_seconds = n_steps * cfg.neuron.dt_ms * 1e-3
     n_neurons = state.hist.shape[1] * state.hist.shape[2]
-    rate = final.spike_count / (n_neurons * sim_seconds)
+    spikes = counters.value(final.spike_count)
     return SimResult(
         state=final,
-        rate_hz=rate,
-        events=final.event_count,
-        spikes=final.spike_count,
+        rate_hz=spikes / (n_neurons * sim_seconds),
+        events=counters.value(final.event_count),
+        spikes=spikes,
         rate_trace=rate_trace,
         params=final_params,
     )

@@ -1,54 +1,54 @@
 """Fused column-step megakernel (Pallas TPU kernel, DESIGN.md §Fusion).
 
-One ``pallas_call`` executes the whole on-shard pipeline of a simulation
-step — LIF+SFA integrate-and-fire, block-event-skipped local synapse
-matmul, remote ELL gather-accumulate, and the STDP pre/post trace
-decay+update — where the unfused ``impl='pallas'`` path issues four
-kernels (``lif_step``, ``synapse_matmul``, ``ell_gather`` and the trace
-update in jnp), each round-tripping the same ``(C, N)`` membrane/trace
+One ``pallas_call`` executes the on-shard neuron pipeline of a simulation
+step — block-event-skipped local synapse matmul, the closing sum with the
+remote and external currents, LIF+SFA integrate-and-fire, and the STDP
+pre/post trace decay+update — where the unfused ``impl='pallas'`` path
+issues two kernels (``synapse_matmul``, ``lif_step``) plus the trace
+update in jnp, each round-tripping the same ``(C, N)`` membrane/trace
 state and spike slices through HBM.
 
-Grid ``(C_pad/BLK_C, N_pad/BLK_S)`` over column tiles with the source-
-block axis innermost. ``BLK_C`` (columns per tile) adapts to the VMEM
-budget: 1 at the paper's column size (N=1240 — the 640 KB weight tile +
-~2.6 MB ELL block dominate), up to ``MAX_BLK_C`` (16) for test/bench
-geometries where a column is small and per-kernel fixed costs would
-otherwise dominate.
-Per (column tile, source block) the kernel
+Remote ELL delivery is *not* in the kernel: Mosaic has no vector gather
+for a VMEM-resident table row, so the caller computes the remote
+currents with the reference's XLA gather
+(``core/network.deliver_remote_ref``) and passes them in. The kernel then
+adds them with the very expression the reference uses.
 
-1. accumulates the local delivery ``spikes @ w_local`` into a VMEM-
-   resident f32 accumulator block, **skipping** the batched MXU tile
-   whenever the tile's spike slice is all-zero (the silent-tile skip of
-   ``synapse_matmul``; at ``BLK_C == 1`` — the paper-scale configuration
-   — this is exactly the per-column 128-block skip), then at the last
-   source block
-2. gathers the remote ELL contributions from the VMEM-pinned neighbour
-   table rows, adds the external drive, and
-3. runs the LIF+SFA threshold dynamics and (under STDP) the exponential
-   trace decay+bump — all while membrane potentials, adaptation, input
-   currents and traces stay resident in VMEM.
+Layout. Every per-column vector is passed as ``(C, 1, N)`` so that its
+last two block dims equal the array's (the TPU (8, 128) block rule holds
+for any N); the weights are ``(C, N, N)`` with one whole column per block.
+Nothing is padded along N. Grid ``(C / BLK_C,)`` over column tiles; per
+tile the kernel
 
-HBM traffic per column tile: one read of state + weights + table row,
-one write of new state + spikes (+ traces). VMEM at the paper's column
-size (N=1240, padded 1280, BLK_C=1): 640 KB weight tile + ~120 KB table
-row + ~2.6 MB ELL idx/weights + ~13 (1, N) vectors ≈ 3.4 MB — well
-under the ~16 MB/core budget (DESIGN.md §Fusion has the table).
+1. accumulates the local delivery ``spikes @ w_local`` in 128-row source
+   slices into a VMEM f32 scratch accumulator, **skipping** the MXU work
+   of every (column, slice) whose spikes are all zero — at the paper's
+   ~5 Hz about half the 128-neuron slices of a column are silent in any
+   step, then
+2. adds the remote and external currents and runs the LIF+SFA threshold
+   dynamics and (under STDP) the exponential trace decay+bump, all while
+   membrane potentials, adaptation and traces stay resident in VMEM.
+
+``BLK_C`` (columns per tile) is the largest divisor of C whose weight
+blocks fit ``VMEM_TILE_BUDGET``: 1 at the paper's column size (N=1240, a
+6.15 MB weight block), up to ``MAX_BLK_C`` for test geometries where a
+column is small and per-step overhead would otherwise dominate. Being a
+divisor, it never pads the column axis either.
 
 Numerics contract (tests/test_fused_step.py asserts all of it): every
 stage replicates the ``ref`` expressions operation-for-operation (same
-order, same dtypes, batched ``take_along_axis`` gather, decay constants
-computed with the identical jnp calls, the exp-Euler gain pre-folded
-exactly as XLA constant-folds it in the ref path), so for column sizes
-within one source block (N <= 128 — every parity-test geometry)
-**spikes and every event-derived quantity (spike history, counts,
-adaptation, refractory state, STDP traces and plastic weights) are
-bitwise-equal** to the ref path over hundreds of steps. Membrane
-potentials may differ in the final ulp (XLA contracts the sub-threshold
-multiply-add chain with FMAs whose grouping depends on fusion context —
-not observable through the threshold on the tested geometries, and
-never through any event-derived quantity there). Beyond one source
-block the local-matmul partial sums accumulate block-by-block and
-currents match allclose — the contract the unfused Pallas kernels have.
+order, same dtypes, decay constants computed with the identical jnp
+calls, the exp-Euler gain pre-folded exactly as XLA constant-folds it in
+the ref path), so for column sizes within one source slice (N <= 128 —
+every parity-test geometry) **spikes and every event-derived quantity
+(spike history, counts, adaptation, refractory state, STDP traces and
+plastic weights) are bitwise-equal** to the ref path over hundreds of
+steps. Membrane potentials may differ in the final ulp (XLA contracts the
+sub-threshold multiply-add chain with FMAs whose grouping depends on
+fusion context). Beyond one source slice the local-matmul partial sums
+accumulate slice-by-slice and currents match allclose — the contract the
+unfused Pallas kernels have. The local matmul contracts at f32 precision
+(``Precision.HIGHEST``) on the chip as in the reference.
 """
 from __future__ import annotations
 
@@ -57,136 +57,126 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import GuardConfig, NeuronConfig, STDPConfig
-from repro.kernels._padding import pad_to
 
-BLK_S = 128            # source block (MXU contraction dim); also lane pad
-MAX_BLK_C = 16         # column-tile cap (sublane dim)
-VMEM_TILE_BUDGET = 4 << 20   # soft budget for one column tile's blocks
-
-
-def column_block(n_pad: int, t: int, k: int) -> int:
-    """Columns per grid tile: as many as fit the soft VMEM budget.
-
-    Per-column bytes = weight tile slice (BLK_S x n_pad f32) + table row
-    (t f32) + ELL idx+weights (n_pad * k * 8 B). The paper's geometry
-    (N=1240) lands at 1 — the full per-column silent-block skip; small
-    test/bench columns batch up to ``MAX_BLK_C`` so per-kernel fixed
-    costs don't dominate.
-    """
-    per_col = BLK_S * n_pad * 4 + t * 4 + n_pad * k * 8
-    return max(1, min(MAX_BLK_C, VMEM_TILE_BUDGET // max(1, per_col)))
+BLK_S = 128            # source slice (MXU contraction dim)
+MAX_BLK_C = 16         # column-tile cap
+VMEM_TILE_BUDGET = 4 << 20   # soft budget for one tile's weight blocks
+# Scoped-VMEM headroom above the double-buffered weight blocks: the ~14
+# (BLK_C, 1, N) state/trace vectors (each padded to 8 sublanes in VMEM,
+# double-buffered) plus the accumulator take well under 2 MiB at N=1240.
+VMEM_HEADROOM = 4 << 20
 
 
-def _make_kernel(ncfg: NeuronConfig, n_sblk: int, with_stdp: bool,
-                 guard: GuardConfig | None = None, nc: int = 0, n: int = 0,
-                 blk_c: int = 0):
+def column_block(nc: int, n: int, itemsize: int = 4) -> int:
+    """Columns per grid tile: the largest divisor of ``nc`` (capped at
+    ``MAX_BLK_C``) whose (N, N) weight blocks fit the soft VMEM budget;
+    at least 1, which is what the paper's N=1240 columns get."""
+    fit = max(1, min(MAX_BLK_C, VMEM_TILE_BUDGET // (n * n * itemsize)))
+    return max(d for d in range(1, fit + 1) if nc % d == 0)
+
+
+def vmem_limit(blk_c: int, n: int, itemsize: int = 4) -> int:
+    """Scoped-VMEM limit for one tile: the weight block double-buffered
+    plus ``VMEM_HEADROOM``. At N=1240 this is 16.3 MB, just above the
+    16 MiB v5e default, which is why the kernel states it explicitly."""
+    return 2 * blk_c * n * n * itemsize + VMEM_HEADROOM
+
+
+def _make_kernel(ncfg: NeuronConfig, n: int, with_stdp: bool,
+                 guard: GuardConfig | None = None, blk_c: int = 1):
     # Python-float constants close over the kernel exactly as they appear
     # in core/neuron.lif_sfa_step (weak-typed f32 promotion, identical
     # grouping) — bitwise parity depends on it.
     g_c, v_rest, v_reset = ncfg.g_c, ncfg.v_rest, ncfg.v_reset
     v_thr, alpha_c = ncfg.v_threshold, ncfg.alpha_c
     arp_steps = round(ncfg.tau_arp_ms / ncfg.dt_ms)
+    slices = [(s0, min(BLK_S, n - s0)) for s0 in range(0, n, BLK_S)]
 
-    def kernel(sloc_ref, w_ref, tbl_ref, idx_ref, rw_ref, ext_ref,
+    def kernel(par_ref, sloc_ref, w_ref, rem_ref, ext_ref,
                v_ref, c_ref, r_ref, *rest):
         rest = list(rest)
+        acc_ref = rest.pop()              # VMEM scratch accumulator
         go_ref = rest.pop() if guard is not None else None
         if with_stdp:
-            (xpre_ref, xpost_ref, par_ref, cur_ref,
+            (xpre_ref, xpost_ref,
              vo_ref, co_ref, ro_ref, so_ref, xpo_ref, xqo_ref) = rest
         else:
-            (par_ref, cur_ref,
-             vo_ref, co_ref, ro_ref, so_ref) = rest
-        si = pl.program_id(1)
-        # hoisted: program_id must be bound outside pl.when branches
-        ci0 = pl.program_id(0) * blk_c if guard is not None else 0
+            vo_ref, co_ref, ro_ref, so_ref = rest
 
-        @pl.when(si == 0)
-        def _init():
-            cur_ref[...] = jnp.zeros_like(cur_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # block-event skip: a silent (column, source slice) contributes
+        # nothing, so its MXU work is skipped
+        for j in range(blk_c):
+            for s0, sz in slices:
+                s = sloc_ref[j, :, s0:s0 + sz]            # (1, sz)
 
-        s = sloc_ref[...]                 # (BLK_C, BLK_S) delayed spikes
-        # block-event skip: a silent source tile contributes nothing
-        # (at BLK_C == 1 this is the per-column 128-block skip)
-        any_spike = jnp.max(jnp.abs(s)) > 0
+                @pl.when(jnp.max(jnp.abs(s)) > 0)
+                def _acc():
+                    acc_ref[j] += jax.lax.dot_general(
+                        s.astype(w_ref.dtype), w_ref[j, s0:s0 + sz, :],
+                        (((1,), (0,)), ((), ())),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32,
+                    )                                      # (1, N)
 
-        @pl.when(any_spike)
-        def _acc():
-            cur_ref[...] += jax.lax.dot_general(
-                s.astype(w_ref.dtype), w_ref[...],
-                (((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )                             # (BLK_C, N_pad)
+        decay_v, decay_c, gain = par_ref[0], par_ref[1], par_ref[2]
+        dtype = v_ref.dtype
+        # local delivery closes: f32 accumulator -> state dtype
+        # (deliver_local_ref's single einsum->astype cast), then the
+        # remote and external currents in the ref's order
+        cur = acc_ref[...].astype(dtype)
+        cur = cur + rem_ref[...]
+        cur = cur + ext_ref[...]
 
-        @pl.when(si == n_sblk - 1)
-        def _finish():
-            decay_v, decay_c, gain = par_ref[0], par_ref[1], par_ref[2]
-            dtype = v_ref.dtype
-            # local delivery closes: f32 accumulator -> state dtype
-            # (deliver_local_ref's single einsum->astype cast)
-            cur = cur_ref[...].astype(dtype)
-            # remote ELL gather-accumulate from the VMEM-pinned table
-            # rows — the ref's batched take_along_axis, verbatim
-            tbl = tbl_ref[...]            # (BLK_C, T)
-            idx = idx_ref[...]            # (BLK_C, N_pad, K)
-            bc, npad, k = idx.shape
-            g = jnp.take_along_axis(
-                tbl, idx.reshape(bc, npad * k), axis=1
-            ).reshape(bc, npad, k)
-            cur = cur + (g * rw_ref[...]).sum(axis=-1).astype(dtype)
-            cur = cur + ext_ref[...]      # external Poisson drive
+        # LIF+SFA — operation-for-operation lif_sfa_step
+        v0, c0, refrac = v_ref[...], c_ref[...], r_ref[...]
+        drive = cur - g_c * c0
+        v1 = v_rest + (v0 - v_rest) * decay_v + drive * gain
+        refractory = refrac > 0
+        v1 = jnp.where(refractory, v_reset, v1)
+        spikes_b = (v1 >= v_thr) & (~refractory)
+        spikes = spikes_b.astype(dtype)
 
-            # LIF+SFA — operation-for-operation lif_sfa_step
-            v0, c0, refrac = v_ref[...], c_ref[...], r_ref[...]
-            drive = cur - g_c * c0
-            v1 = v_rest + (v0 - v_rest) * decay_v + drive * gain
-            refractory = refrac > 0
-            v1 = jnp.where(refractory, v_reset, v1)
-            spikes_b = (v1 >= v_thr) & (~refractory)
-            spikes = spikes_b.astype(dtype)
+        v_out = jnp.where(spikes_b, v_reset, v1)
+        vo_ref[...] = v_out
+        co_ref[...] = c0 * decay_c + alpha_c * spikes
+        ro_ref[...] = jnp.where(spikes_b, jnp.int32(arp_steps),
+                                jnp.maximum(refrac - 1, 0))
+        so_ref[...] = spikes
 
-            v_out = jnp.where(spikes_b, v_reset, v1)
-            vo_ref[...] = v_out
-            co_ref[...] = c0 * decay_c + alpha_c * spikes
-            ro_ref[...] = jnp.where(spikes_b, jnp.int32(arp_steps),
-                                    jnp.maximum(refrac - 1, 0))
-            so_ref[...] = spikes
+        if with_stdp:
+            # exponential trace decay + spike bump (plasticity.py's
+            # x' = x * exp(-dt/tau) + spikes, same expressions)
+            dp, dm = par_ref[3], par_ref[4]
+            xpo_ref[...] = xpre_ref[...] * dp + spikes
+            xqo_ref[...] = xpost_ref[...] * dm + spikes
 
-            if with_stdp:
-                # exponential trace decay + spike bump (plasticity.py's
-                # x' = x * exp(-dt/tau) + spikes, same expressions)
-                dp, dm = par_ref[3], par_ref[4]
-                xpo_ref[...] = xpre_ref[...] * dp + spikes
-                xqo_ref[...] = xpost_ref[...] * dm + spikes
-
-            if guard is not None:
-                # fused guard reduction: per-column NaN/bounds bitflags
-                # over valid rows/lanes only (padding is excluded so a
-                # zero pad lane can never mask or cause a trip)
-                row = ci0 + jax.lax.broadcasted_iota(
-                    jnp.int32, v_out.shape, 0)
-                lane = jax.lax.broadcasted_iota(jnp.int32, v_out.shape, 1)
-                valid = (row < nc) & (lane < n)
-                bad_nan = valid & ~jnp.isfinite(v_out)
-                bad_rng = valid & ((v_out < guard.v_floor)
-                                   | (v_out > guard.v_ceil))
-                go_ref[...] = (
-                    bad_nan.any(axis=1, keepdims=True).astype(jnp.int32)
-                    | (bad_rng.any(axis=1, keepdims=True).astype(jnp.int32)
-                       << 1))
+        if guard is not None:
+            # fused guard reduction: per-column NaN/bounds bitflags over
+            # the column's N neurons, broadcast along the 128 lanes of
+            # the flag block
+            bad_nan = (~jnp.isfinite(v_out)).astype(jnp.float32)
+            bad_rng = ((v_out < guard.v_floor)
+                       | (v_out > guard.v_ceil)).astype(jnp.float32)
+            nan_f = jnp.max(bad_nan, axis=2, keepdims=True) > 0
+            rng_f = jnp.max(bad_rng, axis=2, keepdims=True) > 0
+            flags = (nan_f.astype(jnp.int32)
+                     | (rng_f.astype(jnp.int32) << 1))    # (BLK_C, 1, 1)
+            go_ref[...] = jnp.broadcast_to(flags, go_ref.shape)
 
     return kernel
 
 
 @functools.partial(jax.jit,
                    static_argnames=("ncfg", "scfg", "gcfg", "interpret"))
-def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, s_flat,
-               rem_flat, rem_w, ext, x_pre=None, x_post=None, *,
+def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
+               ext, x_pre=None, x_post=None, *,
                scfg: STDPConfig | None = None,
                gcfg: GuardConfig | None = None,
-               interpret: bool | None = None):
+               interpret: bool):
     """One fused on-shard step over all columns of a shard.
 
     Inputs (C = columns on this shard, N = neurons/column):
@@ -194,8 +184,8 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, s_flat,
     * ``v, c, refrac``       (C, N) LIF state
     * ``s_loc``              (C, N) delayed local spike frame
     * ``w_local``            (C, N, N) intra-column weights [src, tgt]
-    * ``s_flat``             (C, T) delayed neighbour-spike table
-    * ``rem_flat, rem_w``    (C, N, K) ELL gather indices / weights
+    * ``rem_cur``            (C, N) remote ELL currents
+                             (``network.deliver_remote_ref``)
     * ``ext``                (C, N) external drive currents
     * ``x_pre, x_post``      (C, N) STDP traces (with ``scfg``)
 
@@ -205,13 +195,9 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, s_flat,
     v' outside guard bounds) reduced inside the megakernel epilogue —
     the integrity guard costs no extra pass over the membrane state.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     with_stdp = scfg is not None
     with_guard = gcfg is not None
     nc, n = v.shape
-    t = s_flat.shape[1]
-    k = rem_flat.shape[-1]
     dtype = v.dtype
     dt = ncfg.dt_ms
     # decay constants via the IDENTICAL jnp expressions the unfused path
@@ -231,63 +217,48 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, s_flat,
     else:
         params = jnp.stack([decay_v, decay_c, gain])
 
-    np_ = n + ((-n) % BLK_S)
-    blk_c = column_block(np_, t, k)
-    n_sblk = np_ // BLK_S
-
-    def pad2(x):
-        return pad_to(pad_to(x, 1, BLK_S), 0, blk_c)
-
-    v_p, c_p, r_p, sloc_p, ext_p = (pad2(x)
-                                    for x in (v, c, refrac, s_loc, ext))
-    w_p = pad_to(pad_to(pad_to(w_local, 1, BLK_S), 2, BLK_S), 0, blk_c)
-    tbl_p = pad_to(s_flat, 0, blk_c)
-    idx_p = pad_to(pad_to(rem_flat, 1, BLK_S), 0, blk_c)
-    rw_p = pad_to(pad_to(rem_w, 1, BLK_S), 0, blk_c)   # idx 0, weight 0
-    nc_p = v_p.shape[0]
-
-    vspec = pl.BlockSpec((blk_c, np_), lambda ci, si: (ci, 0))
-    in_specs = [
-        pl.BlockSpec((blk_c, BLK_S), lambda ci, si: (ci, si)),     # s_loc
-        pl.BlockSpec((blk_c, BLK_S, np_),
-                     lambda ci, si: (ci, si, 0)),                  # w
-        pl.BlockSpec((blk_c, t), lambda ci, si: (ci, 0)),          # table
-        pl.BlockSpec((blk_c, np_, k), lambda ci, si: (ci, 0, 0)),  # idx
-        pl.BlockSpec((blk_c, np_, k), lambda ci, si: (ci, 0, 0)),  # rem_w
-        vspec, vspec, vspec, vspec,                  # ext, v, c, refrac
-    ]
-    args = [sloc_p, w_p, tbl_p, idx_p, rw_p, ext_p, v_p, c_p, r_p]
+    itemsize = jnp.dtype(w_local.dtype).itemsize
+    blk_c = column_block(nc, n, itemsize)
+    vecs = [v, c, refrac]
     if with_stdp:
-        args += [pad2(x_pre), pad2(x_post)]
-        in_specs += [vspec, vspec]
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))             # params
-    args.append(params)
+        vecs += [x_pre, x_post]
+    vspec = pl.BlockSpec((blk_c, 1, n), lambda ci: (ci, 0, 0))
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),                 # params
+        vspec,                                                 # s_loc
+        pl.BlockSpec((blk_c, n, n), lambda ci: (ci, 0, 0)),    # w_local
+        vspec, vspec,                                          # rem, ext
+    ] + [vspec] * len(vecs)
+    args = [params, s_loc[:, None], w_local, rem_cur[:, None],
+            ext[:, None]] + [x[:, None] for x in vecs]
 
     out_shape = [
-        jax.ShapeDtypeStruct((nc_p, np_), jnp.float32),  # f32 accumulator
-        jax.ShapeDtypeStruct((nc_p, np_), dtype),        # v'
-        jax.ShapeDtypeStruct((nc_p, np_), dtype),        # c'
-        jax.ShapeDtypeStruct((nc_p, np_), jnp.int32),    # refrac'
-        jax.ShapeDtypeStruct((nc_p, np_), dtype),        # spikes
+        jax.ShapeDtypeStruct((nc, 1, n), dtype),        # v'
+        jax.ShapeDtypeStruct((nc, 1, n), dtype),        # c'
+        jax.ShapeDtypeStruct((nc, 1, n), jnp.int32),    # refrac'
+        jax.ShapeDtypeStruct((nc, 1, n), dtype),        # spikes
     ]
     if with_stdp:
-        out_shape += [jax.ShapeDtypeStruct((nc_p, np_), dtype)] * 2
+        out_shape += [jax.ShapeDtypeStruct((nc, 1, n), dtype)] * 2
     out_specs = [vspec] * len(out_shape)
     if with_guard:
-        out_shape.append(jax.ShapeDtypeStruct((nc_p, 1), jnp.int32))
-        out_specs.append(pl.BlockSpec((blk_c, 1), lambda ci, si: (ci, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((nc, 1, 128), jnp.int32))
+        out_specs.append(pl.BlockSpec((blk_c, 1, 128),
+                                      lambda ci: (ci, 0, 0)))
 
     out = pl.pallas_call(
-        _make_kernel(ncfg, n_sblk, with_stdp,
-                     guard=gcfg if with_guard else None,
-                     nc=nc, n=n, blk_c=blk_c),
-        grid=(nc_p // blk_c, n_sblk),
+        _make_kernel(ncfg, n, with_stdp,
+                     guard=gcfg if with_guard else None, blk_c=blk_c),
+        grid=(nc // blk_c,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((blk_c, 1, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit(blk_c, n, itemsize)),
         interpret=interpret,
     )(*args)
-    # out[0] is the f32 scratch accumulator — drop it
     if with_guard:
-        return tuple(o[:nc, :n] for o in out[1:-1]) + (out[-1][:nc, 0],)
-    return tuple(o[:nc, :n] for o in out[1:])
+        return tuple(o[:, 0] for o in out[:-1]) + (out[-1][:, 0, 0],)
+    return tuple(o[:, 0] for o in out)

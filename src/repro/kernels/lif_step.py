@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import NeuronConfig
 from repro.kernels._padding import pad_to
@@ -42,11 +43,8 @@ def _kernel(v_ref, c_ref, r_ref, i_ref, params_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
-def lif_step(cfg: NeuronConfig, v, c, refrac, current,
-             *, interpret: bool | None = None):
+def lif_step(cfg: NeuronConfig, v, c, refrac, current, *, interpret: bool):
     """Returns (v', c', refrac', spikes) — see kernels/ref.py oracle."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nc, nn = v.shape
     import math
     params = jnp.array(
@@ -65,7 +63,7 @@ def lif_step(cfg: NeuronConfig, v, c, refrac, current,
         _kernel,
         grid=(pc // BLK_C, pn // BLK_N),
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[spec] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((pc, pn), v.dtype),

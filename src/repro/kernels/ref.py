@@ -2,40 +2,32 @@
 
 These are the correctness ground truth: tests/test_kernels.py sweeps
 shapes/dtypes and asserts the kernels (interpret mode on CPU, compiled on
-TPU) match these to tight tolerances.
+TPU) match these to tight tolerances. Their contractions and outer
+products run at ``Precision.HIGHEST``, so they stay f32 on a TPU (which
+multiplies f32 in one bf16 pass at default precision); on the CPU the
+setting changes nothing.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def synapse_matmul_ref(spikes: jax.Array, w_local: jax.Array) -> jax.Array:
     """Local synaptic delivery: (C,N) x (C,N,N)[src,tgt] -> (C,N)."""
     return jnp.einsum(
-        "cs,cst->ct", spikes, w_local,
+        "cs,cst->ct", spikes, w_local, precision=HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(spikes.dtype)
-
-
-def ell_gather_ref(s_flat: jax.Array, idx: jax.Array,
-                   w: jax.Array) -> jax.Array:
-    """Remote ELL delivery: gather+reduce.
-
-    s_flat (C, T) neighbour-spike table, idx/w (C, N, K) -> (C, N).
-    """
-    c, n, k = idx.shape
-    g = jnp.take_along_axis(s_flat, idx.reshape(c, n * k), axis=1)
-    out = (g.reshape(c, n, k).astype(jnp.float32)
-           * w.astype(jnp.float32)).sum(axis=-1)
-    return out.astype(s_flat.dtype)
 
 
 def stdp_dense_update_ref(w_local, x_pre_exc, spk_exc, spikes, x_post, *,
                           a_plus, a_minus, lr, w_max):
     """Dense local STDP update (mirrors core/plasticity.py local branch)."""
-    pot = jnp.einsum("cs,ct->cst", x_pre_exc, spikes)
-    dep = jnp.einsum("cs,ct->cst", spk_exc, x_post)
+    pot = jnp.einsum("cs,ct->cst", x_pre_exc, spikes, precision=HIGHEST)
+    dep = jnp.einsum("cs,ct->cst", spk_exc, x_post, precision=HIGHEST)
     dw = lr * (a_plus * pot - a_minus * dep)
     return jnp.where(
         w_local > 0, jnp.clip(w_local + dw, 0.0, w_max), w_local

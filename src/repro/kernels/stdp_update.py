@@ -6,24 +6,27 @@ Computes, per column ``c`` and (src, tgt) pair::
                - a_minus * spk_exc[c, s] * x_post[c, t])
     w' = where(w > 0, clip(w + dw, 0, w_max), w)
 
-— the pair-based STDP rule of core/plasticity.py as two rank-1 MXU
-outer products per (BLK_S, BLK_T) tile, with the block-event skip of
+— the pair-based STDP rule of core/plasticity.py as two rank-1 outer
+products per (BLK_S, BLK_T) tile, with the block-event skip of
 synapse_matmul.py (DESIGN.md §2/§Plasticity): the potentiation term is
 zero wherever the *target* block has no spikes and the depression term is
 zero wherever the *source* block has no spikes, so a tile whose source
-AND target spike slices are all silent skips the MXU outer products and
-only re-applies the (elementwise, VPU) clip — keeping it exactly equal
-to the ref rule, which clips unconditionally. At cortical rates (~5 Hz,
-~6 spikes/ms in a 1240-neuron column) the vast majority of 128x128
-tiles take the skip path.
+AND target spike slices are all silent skips the outer products and only
+re-applies the (elementwise) clip — keeping it exactly equal to the ref
+rule, which clips unconditionally. At cortical rates (~5 Hz, ~6
+spikes/ms in a 1240-neuron column) the vast majority of 128x128 tiles
+take the skip path.
 
 Inhibitory sources are handled upstream: ``x_pre_exc``/``spk_exc`` arrive
 pre-masked to excitatory rows, and the ``w > 0`` guard keeps negative
 (inhibitory) and absent (zero) weights exactly unchanged.
 
 Grid (C, S/BLK_S, T/BLK_T); each instance owns one weight tile (read +
-write, ~64 KB f32 at 128x128) plus four (1, 128) vectors — far under the
-VMEM budget, so the pipeline double-buffers tiles.
+write, 64 KB f32 at 128x128) plus four (1, 1, 128) vector slices, passed
+as ``(C, 1, N)`` so the TPU (8, 128) block rule holds. N is not padded:
+a partial edge tile reads unspecified values past N, and they only reach
+outputs past N, which are discarded. The outer products are f32-exact
+(``Precision.HIGHEST``), like the reference's.
 """
 from __future__ import annotations
 
@@ -32,31 +35,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._padding import pad_to
-
-BLK_S = 128   # source block (MXU rows)
-BLK_T = 128   # target block (MXU lanes)
+BLK_S = 128   # source block (rows)
+BLK_T = 128   # target block (lanes)
 
 
-def _kernel(w_ref, xpre_ref, sspk_ref, tspk_ref, xpost_ref, par_ref, o_ref):
-    s_spk = sspk_ref[...]                    # (1, BLK_S) pre spikes (exc)
-    t_spk = tspk_ref[...]                    # (1, BLK_T) post spikes
-    any_event = (jnp.max(s_spk) > 0) | (jnp.max(t_spk) > 0)
+def _outer(col_ref, row_ref):
+    """(1, 1, B) x (1, 1, B) slices -> (B, B) outer product on the MXU
+    (contract the unit dim), exact in f32."""
+    return jax.lax.dot_general(
+        col_ref[0], row_ref[0], (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _fired(ref):
+    """Whether a spike slice holds an event. Compares before reducing, so
+    an unspecified value past N (NaN in interpret mode) reads as silent."""
+    return jnp.max((ref[...] > 0).astype(jnp.float32)) > 0
+
+
+def _kernel(par_ref, w_ref, xpre_ref, sspk_ref, tspk_ref, xpost_ref, o_ref):
+    any_event = _fired(sspk_ref) | _fired(tspk_ref)
     a_plus, a_minus, lr, w_max = [par_ref[i] for i in range(4)]
 
     @pl.when(any_event)
     def _update():
         w = w_ref[0]                         # (BLK_S, BLK_T)
-        # rank-1 outer products via the MXU (contract the unit dim)
-        pot = jax.lax.dot_general(
-            xpre_ref[...], t_spk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                    # (BLK_S, BLK_T)
-        dep = jax.lax.dot_general(
-            s_spk, xpost_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        pot = _outer(xpre_ref, tspk_ref)
+        dep = _outer(sspk_ref, xpost_ref)
         dw = lr * (a_plus * pot - a_minus * dep)
         o_ref[0] = jnp.where(
             w > 0, jnp.clip(w + dw.astype(w.dtype), 0.0, w_max), w
@@ -65,8 +73,8 @@ def _kernel(w_ref, xpre_ref, sspk_ref, tspk_ref, xpost_ref, par_ref, o_ref):
     @pl.when(~any_event)
     def _silent():
         # the ref rule clips unconditionally (dw == 0 still re-clips a
-        # weight that starts above w_max); skip only the MXU work, not
-        # the clip, so pallas == ref for any input state
+        # weight that starts above w_max); skip only the outer products,
+        # not the clip, so pallas == ref for any input state
         w = w_ref[0]
         o_ref[0] = jnp.where(w > 0, jnp.clip(w, 0.0, w_max), w)
 
@@ -77,37 +85,26 @@ def stdp_dense_update(w_local: jax.Array, x_pre_exc: jax.Array,
                       spk_exc: jax.Array, spikes: jax.Array,
                       x_post: jax.Array, *, a_plus: float, a_minus: float,
                       lr: float, w_max: float,
-                      interpret: bool | None = None) -> jax.Array:
-    """(C, N, N) weights + four (C, N) vectors -> updated (C, N, N).
-
-    Zero-pads N to the 128 lane width; padded weights are zero so the
-    ``w > 0`` guard keeps them zero (exact no-op on the padding).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                      interpret: bool) -> jax.Array:
+    """(C, N, N) weights + four (C, N) vectors -> updated (C, N, N)."""
     c, n = spikes.shape
-    w = pad_to(pad_to(w_local, 1, BLK_S), 2, BLK_T)
-    xpre = pad_to(x_pre_exc, 1, BLK_S)
-    sspk = pad_to(spk_exc, 1, BLK_S)
-    tspk = pad_to(spikes, 1, BLK_T)
-    xpost = pad_to(x_post, 1, BLK_T)
-    n_s, n_t = w.shape[1], w.shape[2]
-    params = jnp.array([a_plus, a_minus, lr, w_max], dtype=w.dtype)
-
-    out = pl.pallas_call(
+    params = jnp.array([a_plus, a_minus, lr, w_max], dtype=w_local.dtype)
+    n_s, n_t = pl.cdiv(n, BLK_S), pl.cdiv(n, BLK_T)
+    src = pl.BlockSpec((1, 1, BLK_S), lambda ci, si, ti: (ci, 0, si))
+    tgt = pl.BlockSpec((1, 1, BLK_T), lambda ci, si, ti: (ci, 0, ti))
+    tile = pl.BlockSpec((1, BLK_S, BLK_T), lambda ci, si, ti: (ci, si, ti))
+    return pl.pallas_call(
         _kernel,
-        grid=(c, n_s // BLK_S, n_t // BLK_T),
-        in_specs=[
-            pl.BlockSpec((1, BLK_S, BLK_T), lambda ci, si, ti: (ci, si, ti)),
-            pl.BlockSpec((1, BLK_S), lambda ci, si, ti: (ci, si)),
-            pl.BlockSpec((1, BLK_S), lambda ci, si, ti: (ci, si)),
-            pl.BlockSpec((1, BLK_T), lambda ci, si, ti: (ci, ti)),
-            pl.BlockSpec((1, BLK_T), lambda ci, si, ti: (ci, ti)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, BLK_S, BLK_T),
-                               lambda ci, si, ti: (ci, si, ti)),
-        out_shape=jax.ShapeDtypeStruct((c, n_s, n_t), w.dtype),
+        grid=(c, n_s, n_t),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  tile, src, src, tgt, tgt],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((c, n, n), w_local.dtype),
+        # each tile is read, then written at the same index: update the
+        # weights in place rather than holding a second (C, N, N) copy
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(w, xpre, sspk, tspk, xpost, params)
-    return out[:, :n, :n]
+    )(params, w_local, x_pre_exc[:, None], spk_exc[:, None],
+      spikes[:, None], x_post[:, None])

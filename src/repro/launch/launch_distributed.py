@@ -1,7 +1,10 @@
 """Spawn-N-processes launcher for the multi-process DPSNN runtime.
 
 The single-machine analogue of the paper's ``mpirun -np N``: spawns N
-worker processes (``repro.runtime.multiprocess``), wires them to a
+CPU worker processes (``repro.runtime.multiprocess``, each started with
+``JAX_PLATFORMS=cpu`` — ranks emulate MPI processes and never open a
+chip; on a TPU host the chip path is one process driving all local
+chips, ``chip_smoke.py --four-chips``), wires them to a
 fresh ``jax.distributed`` coordinator on a free localhost port, waits
 for the job, and — by default — re-runs the identical workload
 single-process in-process and asserts the spike/event totals are
@@ -125,8 +128,12 @@ def launch(args, *, ranks=None, extra=None, hb_dir=None,
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     # each worker is a clean single-device CPU process (ranks are the
-    # parallelism axis; forced host-device counts would nest two axes)
+    # parallelism axis; forced host-device counts would nest two axes).
+    # Ranks emulate MPI processes on the CPU and never open a chip: on a
+    # TPU host a chip belongs to one process, and the chip path is one
+    # process driving all local chips (make_distributed_run).
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     wargv = worker_argv(args) + list(extra or ())
     with tempfile.TemporaryDirectory(prefix="dpsnn-mp-") as tmp:
         procs = []
@@ -378,6 +385,10 @@ def make_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = make_parser().parse_args(argv)
+    # the in-process single-process reference runs where the ranks run:
+    # on the CPU, so the bitwise comparison compares like with like
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
     if args.ranks_per_node and args.batch:
         raise SystemExit(

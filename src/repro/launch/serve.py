@@ -56,8 +56,9 @@ import jax.numpy as jnp
 
 from repro.configs import dpsnn
 from repro.configs.base import DPSNNConfig
-from repro.core import batched
+from repro.core import batched, counters
 from repro.core import simulation as sim
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -259,8 +260,8 @@ class BatchedSimServer:
 
     def _harvest(self, b: int, status: str = "ok") -> JobResult:
         job = self._job[b]
-        spikes = float(np.asarray(self._bstate.spike_count[b]))
-        events = float(np.asarray(self._bstate.event_count[b]))
+        spikes = float(counters.value(self._bstate.spike_count[b]))
+        events = float(counters.value(self._bstate.event_count[b]))
         sim_s = job.n_steps * self.cfg.neuron.dt_ms * 1e-3
         rate = spikes / (self.cfg.n_neurons * sim_s)
         raster = (np.concatenate(self._frames[b], axis=0)
@@ -372,6 +373,7 @@ def make_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = make_parser().parse_args(argv)
+    enable_compile_cache()
     gh, gw = (int(x) for x in args.grid.split("x"))
     cfg = dpsnn.reduced(gh, gw, args.neurons, seed=args.seed,
                         stdp=args.stdp)

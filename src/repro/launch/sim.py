@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import DPSNNConfig
 from repro.core import exchange, metrics as M, simulation as sim
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def parse_grid(s: str):
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--stdp", action="store_true")
     ap.add_argument("--seed", type=int, default=42)
     args = ap.parse_args()
+    enable_compile_cache()
 
     gh, gw = parse_grid(args.grid)
     from repro.configs.base import ExchangeConfig

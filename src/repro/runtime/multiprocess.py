@@ -55,12 +55,19 @@ RESULT_TAG = "DPSNN-RESULT "  # rank 0 prints this + one JSON object
 def init_worker(rank: int, n_ranks: int, coordinator: str) -> None:
     """Join the jax.distributed job as process ``rank`` of ``n_ranks``.
 
-    Must run before any other JAX API touches the backend. On CPU the
-    collectives implementation is switched to gloo (TCP) — the stock CPU
-    client refuses multi-process computations outright.
+    Must run before any other JAX API touches the backend. Ranks emulate
+    the paper's MPI processes on the CPU (the launcher starts them with
+    ``JAX_PLATFORMS=cpu``): the collectives implementation is switched to
+    gloo (TCP) — the stock CPU client refuses multi-process computations
+    outright. On a TPU host the chip path is instead ONE process driving
+    all local chips (``core/exchange.make_distributed_run`` on a mesh of
+    ``jax.devices()``).
     """
     import jax
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
@@ -299,7 +306,7 @@ def worker_run_supervised(cfg, total_steps: int, *, checkpoint_every: int,
     import numpy as np
 
     from repro.checkpoint import checkpointer as ckpt
-    from repro.core import exchange
+    from repro.core import counters, exchange
     from repro.core.partition import make_tile_spec
     from repro.runtime import integrity
     from repro.runtime.fault_tolerance import (CheckpointPolicy,
@@ -402,8 +409,8 @@ def worker_run_supervised(cfg, total_steps: int, *, checkpoint_every: int,
     # worker's chunks. No step_ms key: a supervised run's wall time
     # includes checkpoint IO, so it must not enter the bench gate
     # (benchmarks/compare.py keys on step_ms).
-    spikes = float(np.sum(np.asarray(stacked.spike_count, np.float64)))
-    events = float(np.sum(np.asarray(stacked.event_count, np.float64)))
+    spikes = float(counters.value(np.asarray(stacked.spike_count).sum(0)))
+    events = float(counters.value(np.asarray(stacked.event_count).sum(0)))
     isi_n = float(np.sum(np.asarray(stacked.isi_count, np.float64)))
     isi_mean = float(np.sum(np.asarray(stacked.isi_sum, np.float64)))
     isi_mean = isi_mean / isi_n if isi_n else 0.0

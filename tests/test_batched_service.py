@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import dpsnn as D
-from repro.core import batched
+from repro.core import batched, counters
 from repro.core import simulation as sim
 
 from tests._subproc import run_multidevice
@@ -110,7 +110,7 @@ def test_raster_totals_match_counters():
                               seeds, 15)
     per_raster = np.asarray(out.raster).sum(axis=(0, 2, 3))
     np.testing.assert_array_equal(per_raster,
-                                  np.asarray(out.state.spike_count))
+                                  counters.value(out.state.spike_count))
 
 
 # ---------------------------------------------------------------------------
@@ -154,8 +154,8 @@ def test_server_recycles_slots_under_staggered_durations(stdp):
     for jid, seed, n in jobs:
         ref = _dedicated(cfg, seed, n)
         r = results[jid]
-        assert r.spikes == float(ref.state.spike_count), jid
-        assert r.events == float(ref.state.event_count), jid
+        assert r.spikes == float(ref.spikes), jid
+        assert r.events == float(ref.events), jid
         assert r.raster.shape[0] == n
         assert r.raster.sum() == r.spikes
 
@@ -173,7 +173,7 @@ def test_server_streams_chunks_in_order():
     assert res.raster is None                  # keep_raster=False streams
     assert got == [(0, 4), (4, 4), (8, 2)]     # 10 steps in 4-step chunks
     ref = _dedicated(cfg, cfg.seed, 10)
-    assert res.spikes == float(ref.state.spike_count)
+    assert res.spikes == float(ref.spikes)
 
 
 # ---------------------------------------------------------------------------
@@ -199,9 +199,9 @@ for b in range(2):
     s = jnp.int32(cfg.seed + b)
     state = sim.build(cfg, seed=s)[1]
     ref = sim.run(cfg, params, state, 12, seed=s)
-    assert float(res.spikes[b]) == float(ref.state.spike_count), (
-        b, float(res.spikes[b]), float(ref.state.spike_count))
-    assert float(res.events[b]) == float(ref.state.event_count), b
+    assert float(res.spikes[b]) == float(ref.spikes), (
+        b, float(res.spikes[b]), float(ref.spikes))
+    assert float(res.events[b]) == float(ref.events), b
 print("OK", [float(x) for x in res.spikes])
 """
 

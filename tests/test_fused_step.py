@@ -92,14 +92,11 @@ def test_fused_kernel_output_arity():
     n, c = 32, 3
     z = jnp.zeros((c, n))
     zi = jnp.zeros((c, n), jnp.int32)
-    idx = jnp.zeros((c, n, 4), jnp.int32)
     out = ops.fused_step(cfg.neuron, z, z, zi, z, jnp.zeros((c, n, n)),
-                         jnp.zeros((c, 2 * n)), idx, jnp.zeros((c, n, 4)),
-                         z)
+                         z, z)
     assert len(out) == 4
     out = ops.fused_step(cfg.neuron, z, z, zi, z, jnp.zeros((c, n, n)),
-                         jnp.zeros((c, 2 * n)), idx, jnp.zeros((c, n, 4)),
-                         z, z, z, scfg=cfg.stdp_cfg)
+                         z, z, z, z, scfg=cfg.stdp_cfg)
     assert len(out) == 6
     # silent network stays silent through the fused step
     assert float(jnp.abs(out[3]).max()) == 0.0

@@ -34,23 +34,6 @@ def test_synapse_matmul_all_silent():
     assert float(jnp.abs(out).max()) == 0.0
 
 
-@pytest.mark.parametrize("c,n,k,o", [(2, 64, 16, 4), (3, 130, 17, 20),
-                                     (1, 40, 250, 20)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ell_gather_sweep(c, n, k, o, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(n * k), 3)
-    t = o * n
-    s = (jax.random.uniform(ks[0], (c, t)) < 0.1).astype(dtype)
-    idx = jax.random.randint(ks[1], (c, n, k), 0, t)
-    w = jax.random.normal(ks[2], (c, n, k)).astype(dtype)
-    got = ops.ell_gather(s, idx, w)
-    want = ref.ell_gather_ref(s, idx, w)
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
 @pytest.mark.parametrize("c,n", [(1, 32), (3, 150), (2, 128), (4, 257)])
 def test_stdp_dense_update_sweep(c, n):
     ks = jax.random.split(jax.random.PRNGKey(c * 31 + n), 5)
@@ -132,13 +115,32 @@ def test_pad_to_shared_helper():
     z = jnp.ones((2, 5, 7))
     assert ops.pad_to(z, 1, 5) is z
     assert ops.pad_to(z, 2, 8).shape == (2, 5, 8)
-    # every kernel module uses THIS helper (no private duplicates left)
-    from repro.kernels import (_padding, ell_gather, lif_step, stdp_update,
+    # every kernel module that pads uses THIS helper (no private
+    # duplicates left); the fused and STDP kernels do not pad N at all
+    from repro.kernels import (_padding, fused_step, lif_step, stdp_update,
                                synapse_matmul)
-    for mod in (ell_gather, lif_step, stdp_update, synapse_matmul):
+    for mod in (lif_step, synapse_matmul):
         assert mod.pad_to is _padding.pad_to
+    for mod in (fused_step, lif_step, stdp_update, synapse_matmul):
         assert not hasattr(mod, "_pad_to")
     assert ops.pad_to is _padding.pad_to
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_decision(monkeypatch, backend, want):
+    """kernels/ops.interpret_mode is the one place that decides: the CPU
+    interprets, a TPU compiles, any other backend is refused — at the
+    decision and through a kernel wrapper — instead of interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is not None:
+        assert ops.interpret_mode() is want
+        return
+    with pytest.raises(RuntimeError, match=f"'{backend}'"):
+        ops.interpret_mode()
+    z = jnp.zeros((1, 8))
+    with pytest.raises(RuntimeError, match="interpret"):
+        ops.synapse_matmul(z, jnp.zeros((1, 8, 8)))
 
 
 @settings(max_examples=15, deadline=None)

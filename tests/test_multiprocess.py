@@ -167,9 +167,9 @@ def bad():
     exchange.assert_axis_sizes(wrong, 'data', 'model')
     return frame
 try:
-    exchange._shard_map(bad, mesh=mesh, in_specs=(),
-                        out_specs=jax.sharding.PartitionSpec(),
-                        check_vma=False)()
+    jax.shard_map(bad, mesh=mesh, in_specs=(),
+                  out_specs=jax.sharding.PartitionSpec(),
+                  check_vma=False)()
     print('NO-ERROR')
 except ValueError as e:
     assert 'do not match the tile grid' in str(e), e

@@ -183,41 +183,3 @@ def test_short_delay_stencil_rejected_at_trace_time():
     run, _ = exchange.make_distributed_run(cfg, mesh, n_steps=4)
     with pytest.raises(ValueError, match="overlap requires"):
         run()
-
-
-# ---------------------------------------------------------------------------
-# Tiled ELL kernel (wide neighbour tables)
-# ---------------------------------------------------------------------------
-
-def test_ell_gather_tiled_matches_single_block():
-    """Forcing the table-tiling path (tbl_blk smaller than the row)
-    reproduces the single-block kernel and the jnp oracle, including
-    uneven final chunks."""
-    from repro.core.network import deliver_remote_ref
-    from repro.kernels.ell_gather import ell_gather
-
-    key = jax.random.PRNGKey(7)
-    c, n, k, t = 3, 50, 17, 700
-    s = (jax.random.uniform(key, (c, t)) < 0.2).astype(jnp.float32)
-    idx = jax.random.randint(jax.random.fold_in(key, 1), (c, n, k), 0, t)
-    w = jax.random.normal(jax.random.fold_in(key, 2), (c, n, k))
-    ref = deliver_remote_ref(s, idx, w)
-    one = ell_gather(s, idx, w)                       # single-block path
-    np.testing.assert_allclose(one, ref, atol=1e-5)
-    for blk in (256, 128, 699):                       # even, uneven, t-1
-        tiled = ell_gather(s, idx, w, tbl_blk=blk)
-        np.testing.assert_allclose(tiled, ref, atol=1e-5)
-
-
-def test_wide_stencil_table_exceeds_block_budget_math():
-    """The gauss_exp family at paper scale genuinely needs the tiling:
-    O*N for the radius-6 stencil at N=1240 exceeds the VMEM block."""
-    from repro.configs.dpsnn import with_family
-    from repro.kernels.ell_gather import TBL_BLK
-
-    cfg = with_family(DPSNNConfig(), "gauss_exp")
-    st = build_stencil(cfg)
-    assert st.n_offsets * cfg.neurons_per_column > TBL_BLK
-    # ... while the 2015 Gaussian stencil still takes the fast path
-    st_g = build_stencil(DPSNNConfig())
-    assert st_g.n_offsets * 1240 <= TBL_BLK

@@ -5,15 +5,8 @@ import repro.configs as C
 from repro.runtime.sharding import param_spec
 
 
-def _abstract_mesh(shape, names):
-    try:
-        return AbstractMesh(shape, names)            # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))  # jax 0.4.x
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_expert_stack_ep_rule():

@@ -1,0 +1,141 @@
+"""Compile the main path for a described TPU v5e, without a chip.
+
+The TPU compiler refuses what interpret mode accepts (block shapes off
+the (8, 128) tiling, vector loads from ``ANY``-space refs, in-kernel
+gathers, scoped-VMEM overflow). These tests compile every kernel a
+normal entry point can select, at the paper's column size (N = 1,240),
+with ``interpret=False`` for a described ``v5e:2x2`` topology, and the
+jitted steps that call them — so the chip's compiler checks every change
+at no chip time. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a fixture, never at import (only one
+process may load the TPU library; see the notes on running on a chip in
+README.md). Code under test that asks ``jax.default_backend()`` still
+sees the CPU here, so the step tests steer the interpret decision to
+"compile" with a monkeypatch.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.configs import dpsnn
+from repro.configs.base import GuardConfig, NeuronConfig, STDPConfig
+from repro.kernels import ops
+
+N = 1240          # the paper's neurons per column
+C = 16            # columns in one kernel call
+SMALL_GRID = 2    # columns per side of the compiled whole steps
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler or topology here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+@pytest.fixture
+def compiles_kernels(monkeypatch):
+    """Make the one interpret decision answer 'compile' for this test."""
+    monkeypatch.setattr(ops, "interpret_mode", lambda backend=None: False)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("variant", ["static", "stdp", "guard"])
+def test_fused_step_compiles(one_chip, variant):
+    vec = _spec(one_chip, (C, N))
+    args = [vec, vec, _spec(one_chip, (C, N), jnp.int32), vec,
+            _spec(one_chip, (C, N, N)), vec, vec]
+    kw = {}
+    if variant == "stdp":
+        args += [vec, vec]
+        kw["scfg"] = STDPConfig()
+    if variant == "guard":
+        kw["gcfg"] = GuardConfig(enabled=True)
+    text = _compiled_text(lambda *a: ops.fused_step(
+        NeuronConfig(), *a, interpret=False, **kw), *args)
+    assert "tpu_custom_call" in text
+
+
+def test_stdp_dense_update_compiles(one_chip):
+    vec = _spec(one_chip, (C, N))
+    text = _compiled_text(lambda *a: ops.stdp_dense_update(
+        *a, a_plus=0.01, a_minus=0.012, lr=1.0, w_max=0.84,
+        interpret=False), _spec(one_chip, (C, N, N)), vec, vec, vec, vec)
+    assert "tpu_custom_call" in text
+
+
+def test_synapse_matmul_compiles(one_chip):
+    text = _compiled_text(
+        lambda s, w: ops.synapse_matmul(s, w, interpret=False),
+        _spec(one_chip, (C, N)), _spec(one_chip, (C, N, N)))
+    assert "tpu_custom_call" in text
+
+
+def test_lif_step_compiles(one_chip):
+    vec = _spec(one_chip, (C, N))
+    text = _compiled_text(
+        lambda *a: ops.lif_step(NeuronConfig(), *a, interpret=False),
+        vec, vec, _spec(one_chip, (C, N), jnp.int32), vec)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_fused"])
+def test_single_shard_step_compiles(one_chip, compiles_kernels, impl):
+    """One jitted step of ``core/network.make_step_fn`` at N = 1,240 and
+    the Gaussian stencil's full neighbour-table width, through the
+    interpret decision an entry point takes."""
+    from repro.core import network as net
+    from repro.core import simulation as sim
+
+    cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
+                              grid_w=SMALL_GRID)
+    params, state = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: sim.build(cfg)))
+    text = _compiled_text(net.make_step_fn(cfg, impl=impl), params, state)
+    assert "tpu_custom_call" in text
+
+
+def test_distributed_step_compiles_on_2x2(mesh, compiles_kernels):
+    """``make_distributed_run`` over the four chips of a v5e 2x2: the
+    halo exchange lowers to collective-permutes between chips."""
+    from repro.core import exchange
+
+    cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
+                              grid_w=SMALL_GRID)
+    run, _ = exchange.make_distributed_run(cfg, mesh, n_steps=2,
+                                           impl="pallas_fused")
+    text = run.lower().compile().as_text()
+    assert "collective-permute" in text
+    assert "tpu_custom_call" in text
