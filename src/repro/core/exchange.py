@@ -88,7 +88,7 @@ from repro.core import network as net
 from repro.core import plasticity as plast
 from repro.core.connectivity import StencilSpec, build_stencil
 from repro.core.network import NetworkParams
-from repro.core.neuron import LIFState, lif_sfa_step
+from repro.core.neuron import LIFState
 from repro.core.partition import TileSpec, tile_column_ids
 from repro.core.plasticity import STDPState
 from repro.runtime import integrity
@@ -910,83 +910,84 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
     # saturates (aer_sat flags when one does).
     aer_sat = jnp.zeros((), jnp.bool_)
     new_trace_ext = None
-    if plastic is not None:
-        pre_frame = plastic.traces.x_pre.reshape(
-            spec.tile_h, spec.tile_w, n)
-        if hier or ring_modes is not None:
-            # hierarchical and/or per-ring-mode paths: the trace halo
-            # rides dense f32 on every ring (module invariants), so
-            # pre_ext already carries exact values — interior included
-            if hier:
-                ext_frame, pre_ext, aer_sat = exchange_halo_hier(
-                    state.pending, spec, node, modes=ring_modes,
-                    mode=mode, rate_bound_hz=cfg.conn.aer_rate_bound_hz,
-                    capacity_factor=cfg.conn.aer_capacity_factor,
-                    dt_ms=cfg.neuron.dt_ms, compress=compress,
-                    trace=pre_frame, wrap_shift=wrap)
-            else:
-                ext_frame, pre_ext, aer_sat = exchange_halo_modes(
+    with jax.named_scope("dpsnn.halo"):
+        if plastic is not None:
+            pre_frame = plastic.traces.x_pre.reshape(
+                spec.tile_h, spec.tile_w, n)
+            if hier or ring_modes is not None:
+                # hierarchical and/or per-ring-mode paths: the trace halo
+                # rides dense f32 on every ring (module invariants), so
+                # pre_ext already carries exact values — interior included
+                if hier:
+                    ext_frame, pre_ext, aer_sat = exchange_halo_hier(
+                        state.pending, spec, node, modes=ring_modes,
+                        mode=mode, rate_bound_hz=cfg.conn.aer_rate_bound_hz,
+                        capacity_factor=cfg.conn.aer_capacity_factor,
+                        dt_ms=cfg.neuron.dt_ms, compress=compress,
+                        trace=pre_frame, wrap_shift=wrap)
+                else:
+                    ext_frame, pre_ext, aer_sat = exchange_halo_modes(
+                        state.pending, spec, row_axes, col_axis,
+                        modes=ring_modes,
+                        rate_bound_hz=cfg.conn.aer_rate_bound_hz,
+                        capacity_factor=cfg.conn.aer_capacity_factor,
+                        dt_ms=cfg.neuron.dt_ms, compress=compress,
+                        trace=pre_frame, shift_fn=shift)
+                if plastic.trace_ext is not None:
+                    # keep the (aer_sparse-allocated) halo'd trace table
+                    # maintained with the same values the event-driven
+                    # reconstruction would produce — it holds ext(x_pre(t-1))
+                    # after step t, exactly like the flat AER path
+                    new_trace_ext = pre_ext
+            elif aer:
+                ext_frame, sparse_tr, aer_sat = exchange_halo_aer(
                     state.pending, spec, row_axes, col_axis,
-                    modes=ring_modes,
+                    rate_bound_hz=cfg.conn.aer_rate_bound_hz,
+                    capacity_factor=cfg.conn.aer_capacity_factor,
+                    dt_ms=cfg.neuron.dt_ms, trace=pre_frame, shift_fn=shift)
+                # Event-driven trace-halo reconstruction: the exchanged trace
+                # obeys x_pre(t-1) = x_pre(t-2)*dp + spikes(t-1) at EVERY
+                # neuron, so the halo copy only needs fresh (shipped) values
+                # at spiking addresses — everywhere else the receiver decays
+                # its previous halo frame locally with the same dp the sender
+                # used, which is bitwise-identical (x*dp + 0 == x*dp for the
+                # non-negative traces). Interior is overwritten with the
+                # shard's own exact x_pre.
+                dp = jnp.exp(
+                    -cfg.neuron.dt_ms / cfg.stdp_cfg.tau_plus_ms
+                ).astype(pre_frame.dtype)
+                pre_ext = jnp.where(ext_frame > 0, sparse_tr,
+                                    plastic.trace_ext * dp)
+                pre_ext = jax.lax.dynamic_update_slice(
+                    pre_ext, pre_frame, (r, r, 0))
+                new_trace_ext = pre_ext
+            else:
+                ext_frame, pre_ext = exchange_halo(
+                    state.pending, spec, row_axes, col_axis, compress=compress,
+                    trace=pre_frame, shift_fn=shift)
+        elif hier or ring_modes is not None:
+            if hier:
+                ext_frame, _, aer_sat = exchange_halo_hier(
+                    state.pending, spec, node, modes=ring_modes, mode=mode,
                     rate_bound_hz=cfg.conn.aer_rate_bound_hz,
                     capacity_factor=cfg.conn.aer_capacity_factor,
                     dt_ms=cfg.neuron.dt_ms, compress=compress,
-                    trace=pre_frame, shift_fn=shift)
-            if plastic.trace_ext is not None:
-                # keep the (aer_sparse-allocated) halo'd trace table
-                # maintained with the same values the event-driven
-                # reconstruction would produce — it holds ext(x_pre(t-1))
-                # after step t, exactly like the flat AER path
-                new_trace_ext = pre_ext
+                    wrap_shift=wrap)
+            else:
+                ext_frame, _, aer_sat = exchange_halo_modes(
+                    state.pending, spec, row_axes, col_axis, modes=ring_modes,
+                    rate_bound_hz=cfg.conn.aer_rate_bound_hz,
+                    capacity_factor=cfg.conn.aer_capacity_factor,
+                    dt_ms=cfg.neuron.dt_ms, compress=compress, shift_fn=shift)
         elif aer:
-            ext_frame, sparse_tr, aer_sat = exchange_halo_aer(
+            ext_frame, _, aer_sat = exchange_halo_aer(
                 state.pending, spec, row_axes, col_axis,
                 rate_bound_hz=cfg.conn.aer_rate_bound_hz,
                 capacity_factor=cfg.conn.aer_capacity_factor,
-                dt_ms=cfg.neuron.dt_ms, trace=pre_frame, shift_fn=shift)
-            # Event-driven trace-halo reconstruction: the exchanged trace
-            # obeys x_pre(t-1) = x_pre(t-2)*dp + spikes(t-1) at EVERY
-            # neuron, so the halo copy only needs fresh (shipped) values
-            # at spiking addresses — everywhere else the receiver decays
-            # its previous halo frame locally with the same dp the sender
-            # used, which is bitwise-identical (x*dp + 0 == x*dp for the
-            # non-negative traces). Interior is overwritten with the
-            # shard's own exact x_pre.
-            dp = jnp.exp(
-                -cfg.neuron.dt_ms / cfg.stdp_cfg.tau_plus_ms
-            ).astype(pre_frame.dtype)
-            pre_ext = jnp.where(ext_frame > 0, sparse_tr,
-                                plastic.trace_ext * dp)
-            pre_ext = jax.lax.dynamic_update_slice(
-                pre_ext, pre_frame, (r, r, 0))
-            new_trace_ext = pre_ext
+                dt_ms=cfg.neuron.dt_ms, shift_fn=shift)
         else:
-            ext_frame, pre_ext = exchange_halo(
-                state.pending, spec, row_axes, col_axis, compress=compress,
-                trace=pre_frame, shift_fn=shift)
-    elif hier or ring_modes is not None:
-        if hier:
-            ext_frame, _, aer_sat = exchange_halo_hier(
-                state.pending, spec, node, modes=ring_modes, mode=mode,
-                rate_bound_hz=cfg.conn.aer_rate_bound_hz,
-                capacity_factor=cfg.conn.aer_capacity_factor,
-                dt_ms=cfg.neuron.dt_ms, compress=compress,
-                wrap_shift=wrap)
-        else:
-            ext_frame, _, aer_sat = exchange_halo_modes(
-                state.pending, spec, row_axes, col_axis, modes=ring_modes,
-                rate_bound_hz=cfg.conn.aer_rate_bound_hz,
-                capacity_factor=cfg.conn.aer_capacity_factor,
-                dt_ms=cfg.neuron.dt_ms, compress=compress, shift_fn=shift)
-    elif aer:
-        ext_frame, _, aer_sat = exchange_halo_aer(
-            state.pending, spec, row_axes, col_axis,
-            rate_bound_hz=cfg.conn.aer_rate_bound_hz,
-            capacity_factor=cfg.conn.aer_capacity_factor,
-            dt_ms=cfg.neuron.dt_ms, shift_fn=shift)
-    else:
-        ext_frame = exchange_halo(state.pending, spec, row_axes, col_axis,
-                                  compress=compress, shift_fn=shift)
+            ext_frame = exchange_halo(state.pending, spec, row_axes, col_axis,
+                                      compress=compress, shift_fn=shift)
 
     # (2) ring write (pipelined only, before the reads) ------------------
     # pipelined: consume the PREVIOUS step's exchange — write the carried
@@ -1000,9 +1001,10 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
     # permutes — the write happens after compute, step (4).
     new_ext_pending = None
     if pipelined:
-        hist_ext = jax.lax.dynamic_update_index_in_dim(
-            state.hist_ext, state.ext_pending, (state.t - 2) % d_slots,
-            axis=0)
+        with jax.named_scope("dpsnn.ring"):
+            hist_ext = jax.lax.dynamic_update_index_in_dim(
+                state.hist_ext, state.ext_pending, (state.t - 2) % d_slots,
+                axis=0)
         read_hist = hist_ext
         new_ext_pending = ext_frame
     else:
@@ -1012,13 +1014,15 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
     # local delivery: delay 1 == the carried pending frame (shard-local);
     # remote delivery: delays >= 2 come from the extended ring buffer
     s_loc = state.pending.reshape(c, n)
-    per_offset = []
-    for (dy, dx, _k, delay, _p) in stencil.offsets:
-        frame = jnp.take(read_hist, (state.t - delay) % d_slots, axis=0)
-        block = net.offset_slice(frame, dy, dx, r, spec.tile_h, spec.tile_w,
-                                 n)
-        per_offset.append(block.reshape(c, n))
-    s_flat = jnp.stack(per_offset, axis=1).reshape(c, stencil.n_offsets * n)
+    with jax.named_scope("dpsnn.ring"):
+        per_offset = []
+        for (dy, dx, _k, delay, _p) in stencil.offsets:
+            frame = jnp.take(read_hist, (state.t - delay) % d_slots, axis=0)
+            block = net.offset_slice(frame, dy, dx, r, spec.tile_h,
+                                     spec.tile_w, n)
+            per_offset.append(block.reshape(c, n))
+        s_flat = jnp.stack(per_offset, axis=1).reshape(
+            c, stencil.n_offsets * n)
     col_ids = shard_col_ids(cfg, spec, row_axes, col_axis)
     ext_drive, ext_counts = net.external_drive(cfg, state.t, col_ids,
                                                seed=seed, nu_scale=nu_scale)
@@ -1032,12 +1036,8 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
             plastic.traces if plastic is not None else None,
             s_loc, s_flat, ext_drive)
     else:
-        deliver_local, deliver_remote = net._delivery_fns(impl)
-        currents = deliver_local(s_loc, params.w_local)
-        currents = currents + deliver_remote(s_flat, params.rem_flat,
-                                             params.rem_w)
-        lif, spikes = lif_sfa_step(cfg.neuron, state.lif,
-                                   currents + ext_drive)
+        lif, spikes = net.unfused_stage(cfg, params, state.lif, s_loc,
+                                        s_flat, ext_drive, impl)
 
     # chaos NaN injection lands on the freshly computed membrane state so
     # the guard verdict below detects it within the same step
@@ -1051,12 +1051,14 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
     # (bitwise-equal values => bitwise-equal weight trajectories).
     new_plastic = None
     if plastic is not None:
-        per_tr = [
-            net.offset_slice(pre_ext, dy, dx, r, spec.tile_h, spec.tile_w,
-                             n).reshape(c, n)
-            for (dy, dx, _k, _delay, _p) in stencil.offsets
-        ]
-        table = jnp.stack(per_tr, axis=1).reshape(c, stencil.n_offsets * n)
+        with jax.named_scope("dpsnn.stdp"):
+            per_tr = [
+                net.offset_slice(pre_ext, dy, dx, r, spec.tile_h,
+                                 spec.tile_w, n).reshape(c, n)
+                for (dy, dx, _k, _delay, _p) in stencil.offsets
+            ]
+            table = jnp.stack(per_tr, axis=1).reshape(
+                c, stencil.n_offsets * n)
         is_inh = conn.neuron_types(cfg)
         new_params, traces = plast.stdp_update(
             cfg, cfg.stdp_cfg, params, plastic.traces, spikes, is_inh,
@@ -1072,8 +1074,9 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
     # into the ring AFTER the compute above, so the collective had the
     # whole step's compute to hide behind (first read at t+1)
     if not pipelined:
-        hist_ext = jax.lax.dynamic_update_index_in_dim(
-            state.hist_ext, ext_frame, (state.t - 1) % d_slots, axis=0)
+        with jax.named_scope("dpsnn.ring"):
+            hist_ext = jax.lax.dynamic_update_index_in_dim(
+                state.hist_ext, ext_frame, (state.t - 1) % d_slots, axis=0)
 
     # exact int32 counts, as in network.step_single (core/counters.py)
     k_tot = params.rem_w.shape[-1]

@@ -16,6 +16,14 @@ and ``"pallas_fused"`` (the column-step megakernel). They produce the same
 currents (tests/test_kernels.py and tests/test_fused_step.py assert it).
 Remote ELL delivery is the reference's XLA gather on every path: Mosaic
 has no in-kernel vector gather from a VMEM table row.
+
+Each layer of a step runs under one ``jax.named_scope``, which names its
+ops in the compiled program (``op_name`` metadata) and in a profiler
+trace, and changes no op: ``dpsnn.drive``, ``dpsnn.ring`` (delayed spike
+table, history writes), ``dpsnn.halo`` (core/exchange.py),
+``dpsnn.remote``, ``dpsnn.neuron`` (local delivery, LIF+SFA),
+``dpsnn.stdp`` (core/plasticity.py) and ``dpsnn.params``. The scopes do
+not nest, so each op has at most one; counters and the guard have none.
 """
 from __future__ import annotations
 
@@ -50,14 +58,16 @@ class NetworkState(NamedTuple):
 
 def build_params(cfg: DPSNNConfig, col_ids: jax.Array) -> NetworkParams:
     stencil = build_stencil(cfg)
-    w_local, rem_idx, rem_w = conn.generate_columns(cfg, col_ids)
-    rem_flat = conn.flat_gather_index(stencil, rem_idx, cfg.neurons_per_column)
-    return NetworkParams(
-        w_local=w_local,
-        rem_flat=rem_flat,
-        rem_w=rem_w,
-        local_outdeg=conn.local_out_degree(w_local).astype(jnp.float32),
-    )
+    with jax.named_scope("dpsnn.params"):
+        w_local, rem_idx, rem_w = conn.generate_columns(cfg, col_ids)
+        rem_flat = conn.flat_gather_index(stencil, rem_idx,
+                                          cfg.neurons_per_column)
+        return NetworkParams(
+            w_local=w_local,
+            rem_flat=rem_flat,
+            rem_w=rem_w,
+            local_outdeg=conn.local_out_degree(w_local).astype(jnp.float32),
+        )
 
 
 def init_state(cfg: DPSNNConfig, col_ids: jax.Array,
@@ -130,10 +140,11 @@ def deliver_remote_ref(s_flat: jax.Array, rem_flat: jax.Array,
     returns   (C, N) currents
     """
     c, n, k = rem_flat.shape
-    gathered = jnp.take_along_axis(
-        s_flat, rem_flat.reshape(c, n * k), axis=1
-    ).reshape(c, n, k)
-    return (gathered * rem_w).sum(axis=-1).astype(s_flat.dtype)
+    with jax.named_scope("dpsnn.remote"):
+        gathered = jnp.take_along_axis(
+            s_flat, rem_flat.reshape(c, n * k), axis=1
+        ).reshape(c, n, k)
+        return (gathered * rem_w).sum(axis=-1).astype(s_flat.dtype)
 
 
 def _delivery_fns(impl: str):
@@ -173,14 +184,15 @@ def neighbour_table_single(hist: jax.Array, t: jax.Array,
     gh, gw = grid_hw
     d_slots, c_cols, n = hist.shape
     r = stencil.radius
-    per_offset = []
-    for (dy, dx, _k, delay, _p) in stencil.offsets:
-        s = jnp.take(hist, (t - delay) % d_slots, axis=0)   # (C, N)
-        g = jnp.pad(s.reshape(gh, gw, n), ((r, r), (r, r), (0, 0)))
-        g = offset_slice(g, dy, dx, r, gh, gw, n)
-        per_offset.append(g.reshape(c_cols, n))
-    s_ext = jnp.stack(per_offset, axis=1)                    # (C, O, N)
-    return s_ext.reshape(c_cols, stencil.n_offsets * n)
+    with jax.named_scope("dpsnn.ring"):
+        per_offset = []
+        for (dy, dx, _k, delay, _p) in stencil.offsets:
+            s = jnp.take(hist, (t - delay) % d_slots, axis=0)   # (C, N)
+            g = jnp.pad(s.reshape(gh, gw, n), ((r, r), (r, r), (0, 0)))
+            g = offset_slice(g, dy, dx, r, gh, gw, n)
+            per_offset.append(g.reshape(c_cols, n))
+        s_ext = jnp.stack(per_offset, axis=1)                # (C, O, N)
+        return s_ext.reshape(c_cols, stencil.n_offsets * n)
 
 
 # ---------------------------------------------------------------------------
@@ -201,17 +213,20 @@ def external_drive(cfg: DPSNNConfig, t: jax.Array, col_ids: jax.Array, *,
     *textually identical* to the single-tenant code, the basis of the
     B=1 bitwise guarantee (DESIGN.md §Service)."""
     lam = cfg.c_ext * cfg.nu_ext_hz * cfg.neuron.dt_ms * 1e-3
-    if nu_scale is not None:
-        lam = jnp.float32(lam) * nu_scale
     n = cfg.neurons_per_column
-    base = jax.random.fold_in(
-        jax.random.PRNGKey((cfg.seed if seed is None else seed) + 0xE57), t)
+    with jax.named_scope("dpsnn.drive"):
+        if nu_scale is not None:
+            lam = jnp.float32(lam) * nu_scale
+        base = jax.random.fold_in(
+            jax.random.PRNGKey((cfg.seed if seed is None else seed) + 0xE57),
+            t)
 
-    def col_drive(cid):
-        return jax.random.poisson(jax.random.fold_in(base, cid), lam, (n,))
+        def col_drive(cid):
+            return jax.random.poisson(jax.random.fold_in(base, cid), lam,
+                                      (n,))
 
-    counts = jax.vmap(col_drive)(col_ids)
-    return counts.astype(jnp.dtype(cfg.dtype)) * cfg.conn.j_ext, counts
+        counts = jax.vmap(col_drive)(col_ids)
+        return counts.astype(jnp.dtype(cfg.dtype)) * cfg.conn.j_ext, counts
 
 
 def step_single(cfg: DPSNNConfig, params: NetworkParams,
@@ -238,9 +253,10 @@ def step_single(cfg: DPSNNConfig, params: NetworkParams,
     d_slots = state.hist.shape[0]
 
     # 1. recurrent delivery from delayed history
-    s_loc = jnp.take(
-        state.hist, (state.t - cfg.conn.min_delay_steps) % d_slots, axis=0
-    )
+    with jax.named_scope("dpsnn.ring"):
+        s_loc = jnp.take(
+            state.hist, (state.t - cfg.conn.min_delay_steps) % d_slots,
+            axis=0)
     s_flat = neighbour_table_single(state.hist, state.t, stencil, grid_hw)
 
     # 2. external Poisson drive
@@ -254,12 +270,8 @@ def step_single(cfg: DPSNNConfig, params: NetworkParams,
         lif, spikes, new_stdp, gflags = fused_stage(
             cfg, params, state.lif, state.stdp, s_loc, s_flat, ext)
     else:
-        deliver_local, deliver_remote = _delivery_fns(impl)
-        currents = deliver_local(s_loc, params.w_local)
-        currents = currents + deliver_remote(s_flat, params.rem_flat,
-                                             params.rem_w)
-        currents = currents + ext
-        lif, spikes = lif_sfa_step(cfg.neuron, state.lif, currents)
+        lif, spikes = unfused_stage(cfg, params, state.lif, s_loc, s_flat,
+                                    ext, impl)
 
     # 3b. in-band integrity guard (DESIGN.md §Integrity): chaos NaN
     # injection lands on the freshly computed membrane state so the
@@ -283,9 +295,9 @@ def step_single(cfg: DPSNNConfig, params: NetworkParams,
                                            step_code=code, t=state.t)
 
     # 4. write new spikes into the ring buffer
-    hist = jax.lax.dynamic_update_index_in_dim(
-        state.hist, spikes, state.t % d_slots, axis=0
-    )
+    with jax.named_scope("dpsnn.ring"):
+        hist = jax.lax.dynamic_update_index_in_dim(
+            state.hist, spikes, state.t % d_slots, axis=0)
 
     # 5. synaptic-event accounting (the paper's normalisation unit):
     #    every emitted spike is delivered to its realized local out-degree
@@ -324,24 +336,38 @@ def fused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
     gcfg = cfg.guard if cfg.guard.enabled else None
     gflags = None
     rem = deliver_remote_ref(s_flat, params.rem_flat, params.rem_w)
-    if cfg.stdp:
-        out = ops.fused_step(
-            cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
-            params.w_local, rem, ext,
-            stdp0.x_pre, stdp0.x_post, scfg=cfg.stdp_cfg, gcfg=gcfg)
-        v, c, refrac, spikes, x_pre, x_post = out[:6]
-        if gcfg is not None:
-            gflags = out[6]
-        stdp1 = stdp0._replace(x_pre=x_pre, x_post=x_post)
-    else:
-        out = ops.fused_step(
-            cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
-            params.w_local, rem, ext, gcfg=gcfg)
-        v, c, refrac, spikes = out[:4]
-        if gcfg is not None:
-            gflags = out[4]
-        stdp1 = stdp0
+    with jax.named_scope("dpsnn.neuron"):
+        if cfg.stdp:
+            out = ops.fused_step(
+                cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
+                params.w_local, rem, ext,
+                stdp0.x_pre, stdp0.x_post, scfg=cfg.stdp_cfg, gcfg=gcfg)
+            v, c, refrac, spikes, x_pre, x_post = out[:6]
+            if gcfg is not None:
+                gflags = out[6]
+            stdp1 = stdp0._replace(x_pre=x_pre, x_post=x_post)
+        else:
+            out = ops.fused_step(
+                cfg.neuron, lif0.v, lif0.c, lif0.refrac, s_loc,
+                params.w_local, rem, ext, gcfg=gcfg)
+            v, c, refrac, spikes = out[:4]
+            if gcfg is not None:
+                gflags = out[4]
+            stdp1 = stdp0
     return LIFState(v=v, c=c, refrac=refrac), spikes, stdp1, gflags
+
+
+def unfused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
+                  s_loc: jax.Array, s_flat: jax.Array, ext: jax.Array,
+                  impl: str):
+    """Delivery and the neuron update as separate stages (``impl`` 'ref'
+    or 'pallas'), shared by both loops. Returns ``(lif', spikes)``."""
+    deliver_local, deliver_remote = _delivery_fns(impl)
+    with jax.named_scope("dpsnn.neuron"):
+        local = deliver_local(s_loc, params.w_local)
+    remote = deliver_remote(s_flat, params.rem_flat, params.rem_w)
+    with jax.named_scope("dpsnn.neuron"):
+        return lif_sfa_step(cfg.neuron, lif0, local + remote + ext)
 
 
 def make_step_fn(cfg: DPSNNConfig, *, impl: str = "ref"):

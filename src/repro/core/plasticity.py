@@ -53,12 +53,14 @@ def pre_trace_table(x_pre: jax.Array, stencil: StencilSpec,
     gh, gw = grid_hw
     c, n = x_pre.shape
     r = stencil.radius
-    g = jnp.pad(x_pre.reshape(gh, gw, n), ((r, r), (r, r), (0, 0)))
-    per_offset = [
-        net.offset_slice(g, dy, dx, r, gh, gw, n).reshape(c, n)
-        for (dy, dx, _k, _delay, _p) in stencil.offsets
-    ]
-    return jnp.stack(per_offset, axis=1).reshape(c, stencil.n_offsets * n)
+    with jax.named_scope("dpsnn.stdp"):
+        g = jnp.pad(x_pre.reshape(gh, gw, n), ((r, r), (r, r), (0, 0)))
+        per_offset = [
+            net.offset_slice(g, dy, dx, r, gh, gw, n).reshape(c, n)
+            for (dy, dx, _k, _delay, _p) in stencil.offsets
+        ]
+        return jnp.stack(per_offset, axis=1).reshape(
+            c, stencil.n_offsets * n)
 
 
 def stdp_update(cfg: DPSNNConfig, scfg: STDPConfig, params: NetworkParams,
@@ -78,59 +80,60 @@ def stdp_update(cfg: DPSNNConfig, scfg: STDPConfig, params: NetworkParams,
     here, bitwise-identical to the recomputation.
     Returns (new_params, new_stdp_state).
     """
-    dt = cfg.neuron.dt_ms
-    if new_traces is not None:
-        x_pre, x_post = new_traces.x_pre, new_traces.x_post
-    else:
-        dp = jnp.exp(-dt / scfg.tau_plus_ms).astype(st.x_pre.dtype)
-        dm = jnp.exp(-dt / scfg.tau_minus_ms).astype(st.x_pre.dtype)
-        x_pre = st.x_pre * dp + spikes
-        x_post = st.x_post * dm + spikes
+    with jax.named_scope("dpsnn.stdp"):
+        dt = cfg.neuron.dt_ms
+        if new_traces is not None:
+            x_pre, x_post = new_traces.x_pre, new_traces.x_post
+        else:
+            dp = jnp.exp(-dt / scfg.tau_plus_ms).astype(st.x_pre.dtype)
+            dm = jnp.exp(-dt / scfg.tau_minus_ms).astype(st.x_pre.dtype)
+            x_pre = st.x_pre * dp + spikes
+            x_post = st.x_post * dm + spikes
 
-    exc_src = (~is_inh).astype(spikes.dtype)          # (N,)
-    w_max = scfg.w_max_factor * cfg.conn.j_exc
+        exc_src = (~is_inh).astype(spikes.dtype)          # (N,)
+        w_max = scfg.w_max_factor * cfg.conn.j_exc
 
-    # --- local dense blocks: two outer products per column ---
-    # single source of truth for the dense rule: kernels/ref.py oracle
-    # (the pallas kernel is tested bitwise-equal against it)
-    x_pre_exc = x_pre * exc_src[None, :]
-    spk_exc = spikes * exc_src[None, :]
-    kw = dict(a_plus=scfg.a_plus, a_minus=scfg.a_minus, lr=scfg.lr,
-              w_max=w_max)
-    if impl in ("pallas", "pallas_fused"):
-        # the dense weight write is a second full pass over (C, N, N) —
-        # it stays the standalone block-event-skipping kernel even under
-        # the fused step (the megakernel's weight tiles are consumed
-        # before this step's spikes exist, DESIGN.md §Fusion)
-        from repro.kernels import ops
-        w_local = ops.stdp_dense_update(
-            params.w_local, x_pre_exc, spk_exc, spikes, x_post, **kw)
-    elif impl == "ref":
-        from repro.kernels import ref as kref
-        w_local = kref.stdp_dense_update_ref(
-            params.w_local, x_pre_exc, spk_exc, spikes, x_post, **kw)
-    else:
-        raise ValueError(f"unknown stdp impl {impl!r}")
+        # --- local dense blocks: two outer products per column ---
+        # single source of truth for the dense rule: kernels/ref.py oracle
+        # (the pallas kernel is tested bitwise-equal against it)
+        x_pre_exc = x_pre * exc_src[None, :]
+        spk_exc = spikes * exc_src[None, :]
+        kw = dict(a_plus=scfg.a_plus, a_minus=scfg.a_minus, lr=scfg.lr,
+                  w_max=w_max)
+        if impl in ("pallas", "pallas_fused"):
+            # the dense weight write is a second full pass over (C, N, N) —
+            # it stays the standalone block-event-skipping kernel even under
+            # the fused step (the megakernel's weight tiles are consumed
+            # before this step's spikes exist, DESIGN.md §Fusion)
+            from repro.kernels import ops
+            w_local = ops.stdp_dense_update(
+                params.w_local, x_pre_exc, spk_exc, spikes, x_post, **kw)
+        elif impl == "ref":
+            from repro.kernels import ref as kref
+            w_local = kref.stdp_dense_update_ref(
+                params.w_local, x_pre_exc, spk_exc, spikes, x_post, **kw)
+        else:
+            raise ValueError(f"unknown stdp impl {impl!r}")
 
-    rem_w = params.rem_w
-    if pre_trace_table is not None and rem_flat is not None:
-        c, n, k = rem_flat.shape
-        pre_tr = jnp.take_along_axis(
-            pre_trace_table, rem_flat.reshape(c, n * k), axis=1
-        ).reshape(c, n, k)
-        # remote post side: this column's own spikes / traces
-        dw_r = scfg.lr * (
-            scfg.a_plus * pre_tr * spikes[:, :, None]
-            # depression for remote needs the *pre spike* table; the trace
-            # table at tau->0 approximates it — we reuse pre_tr with the
-            # post-trace, the standard pair-based asymmetry:
-            - scfg.a_minus * pre_tr * x_post[:, :, None] * 0.5
-        )
-        rem_w = jnp.where(
-            params.rem_w > 0,
-            jnp.clip(params.rem_w + dw_r, 0.0, w_max),
-            params.rem_w,
-        )
+        rem_w = params.rem_w
+        if pre_trace_table is not None and rem_flat is not None:
+            c, n, k = rem_flat.shape
+            pre_tr = jnp.take_along_axis(
+                pre_trace_table, rem_flat.reshape(c, n * k), axis=1
+            ).reshape(c, n, k)
+            # remote post side: this column's own spikes / traces
+            dw_r = scfg.lr * (
+                scfg.a_plus * pre_tr * spikes[:, :, None]
+                # depression for remote needs the *pre spike* table; the trace
+                # table at tau->0 approximates it — we reuse pre_tr with the
+                # post-trace, the standard pair-based asymmetry:
+                - scfg.a_minus * pre_tr * x_post[:, :, None] * 0.5
+            )
+            rem_w = jnp.where(
+                params.rem_w > 0,
+                jnp.clip(params.rem_w + dw_r, 0.0, w_max),
+                params.rem_w,
+            )
 
-    new_params = params._replace(w_local=w_local, rem_w=rem_w)
-    return new_params, STDPState(x_pre=x_pre, x_post=x_post)
+        new_params = params._replace(w_local=w_local, rem_w=rem_w)
+        return new_params, STDPState(x_pre=x_pre, x_post=x_post)

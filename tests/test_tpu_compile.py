@@ -15,6 +15,7 @@ sees the CPU here, so the step tests steer the interpret decision to
 "compile" with a monkeypatch.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +140,93 @@ def test_distributed_step_compiles_on_2x2(mesh, compiles_kernels):
     text = run.lower().compile().as_text()
     assert "collective-permute" in text
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# Named scopes on the chip's compiled program: the ops the benchmark's
+# per-layer metrics read carry the scope of their layer
+# ---------------------------------------------------------------------------
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_COMP = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_SCOPE = re.compile(r'metadata=\{[^}]*?op_name="[^"]*?(dpsnn\.[\w\-]+)')
+_SHAPE = re.compile(r"^\(?[a-z0-9]+\[([\d,]*)\]")
+
+
+def _instructions(text):
+    """[(computation, name, rest of line)] of an HLO module's text."""
+    out, comp = [], None
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m and "=" not in line.split("{")[0]:
+            comp = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if m and comp is not None:
+            out.append((comp, m.group(1), m.group(2)))
+    return out
+
+
+def _scope(rhs):
+    m = _SCOPE.search(rhs)
+    return m.group(1) if m else None
+
+
+def _elements(rhs):
+    dims = _SHAPE.match(rhs).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def test_single_shard_step_scopes(one_chip, compiles_kernels):
+    """The largest gather is remote delivery, and the one kernel of a
+    static step is the neuron layer's."""
+    from repro.core import network as net
+    from repro.core import simulation as sim
+
+    cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
+                              grid_w=SMALL_GRID)
+    params, state = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: sim.build(cfg)))
+    instrs = _instructions(_compiled_text(
+        net.make_step_fn(cfg, impl="pallas_fused"), params, state))
+    caller = {re.search(r"calls=%?([\w.\-]+)", rhs).group(1): rhs
+              for _, _, rhs in instrs if " fusion(" in rhs}
+    comp, _, rhs = max(((c, n, r) for c, n, r in instrs if " gather(" in r),
+                       key=lambda x: _elements(x[2]))
+    # the trace names the fusion that holds it, by the fusion's own scope
+    assert _scope(caller.get(comp, rhs)) == "dpsnn.remote"
+    kernels = [r for _, _, r in instrs if "tpu_custom_call" in r]
+    assert kernels and all(_scope(r) == "dpsnn.neuron" for r in kernels)
+
+
+def test_plastic_run_kernel_scopes(one_chip, compiles_kernels):
+    """Under STDP each Mosaic kernel is the neuron layer's (fused step)
+    or the plasticity layer's (dense STDP update)."""
+    from repro.core import simulation as sim
+
+    cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
+                              grid_w=SMALL_GRID, stdp=True)
+    params, state = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: sim.build(cfg)))
+    text = sim.run.lower(cfg, params, state, 1,
+                         impl="pallas_fused").compile().as_text()
+    kernels = [_scope(r) for _, _, r in _instructions(text)
+               if "tpu_custom_call" in r]
+    assert sorted(kernels) == ["dpsnn.neuron", "dpsnn.stdp"]
+
+
+def test_distributed_step_scopes_on_2x2(mesh, compiles_kernels):
+    """Every collective-permute between the four chips is the halo
+    exchange's."""
+    from repro.core import exchange
+
+    cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
+                              grid_w=SMALL_GRID)
+    run, _ = exchange.make_distributed_run(cfg, mesh, n_steps=2,
+                                           impl="pallas_fused")
+    permutes = [r for _, _, r in _instructions(run.lower().compile()
+                                               .as_text())
+                if re.search(r" collective-permute(-start|-done)?\(", r)]
+    assert permutes and all(_scope(r) == "dpsnn.halo" for r in permutes)
