@@ -14,8 +14,10 @@ Local delivery has interchangeable implementations selected by ``impl``:
 ``"ref"`` (pure jnp, the oracle), ``"pallas"`` (kernels/synapse_matmul)
 and ``"pallas_fused"`` (the column-step megakernel). They produce the same
 currents (tests/test_kernels.py and tests/test_fused_step.py assert it).
-Remote ELL delivery is the reference's XLA gather on every path: Mosaic
-has no in-kernel vector gather from a VMEM table row.
+Remote ELL delivery under ``"ref"`` is the reference's XLA gather
+(:func:`deliver_remote_ref`, the oracle); both Pallas impls run
+kernels/ell_deliver.py over the spike table packed 32 spikes to a word
+(:func:`deliver_remote_packed`), which needs no gather.
 
 Each layer of a step runs under one ``jax.named_scope``, which names its
 ops in the compiled program (``op_name`` metadata) and in a profiler
@@ -27,6 +29,7 @@ not nest, so each op has at most one; counters and the guard have none.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -147,12 +150,27 @@ def deliver_remote_ref(s_flat: jax.Array, rem_flat: jax.Array,
         return (gathered * rem_w).sum(axis=-1).astype(s_flat.dtype)
 
 
-def _delivery_fns(impl: str):
+def deliver_remote_packed(s_flat: jax.Array, rem_flat: jax.Array,
+                          rem_w: jax.Array, *,
+                          stencil: StencilSpec) -> jax.Array:
+    """ELL delivery through the Pallas kernel (kernels/ell_deliver.py):
+    the same currents as :func:`deliver_remote_ref`, read from the spike
+    table packed 32 spikes to a word, with no gather."""
+    from repro.kernels import ops
+    slots = tuple(k for (_dy, _dx, k, _d, _p) in stencil.offsets)
+    with jax.named_scope("dpsnn.remote"):
+        words = ops.pack_spikes(s_flat, stencil.n_offsets)
+        return ops.ell_deliver(words, rem_flat, rem_w, slots=slots,
+                               out_dtype=s_flat.dtype)
+
+
+def _delivery_fns(impl: str, stencil: StencilSpec):
     if impl == "ref":
         return deliver_local_ref, deliver_remote_ref
     if impl == "pallas":
         from repro.kernels import ops
-        return ops.synapse_matmul, deliver_remote_ref
+        return ops.synapse_matmul, functools.partial(deliver_remote_packed,
+                                                     stencil=stencil)
     raise ValueError(
         f"unknown delivery impl {impl!r} (expected 'ref' or 'pallas'; "
         f"'pallas_fused' runs the whole step as one megakernel and is "
@@ -335,7 +353,8 @@ def fused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
     from repro.kernels import ops
     gcfg = cfg.guard if cfg.guard.enabled else None
     gflags = None
-    rem = deliver_remote_ref(s_flat, params.rem_flat, params.rem_w)
+    rem = deliver_remote_packed(s_flat, params.rem_flat, params.rem_w,
+                                stencil=build_stencil(cfg))
     with jax.named_scope("dpsnn.neuron"):
         if cfg.stdp:
             out = ops.fused_step(
@@ -362,7 +381,7 @@ def unfused_stage(cfg: DPSNNConfig, params: NetworkParams, lif0: LIFState,
                   impl: str):
     """Delivery and the neuron update as separate stages (``impl`` 'ref'
     or 'pallas'), shared by both loops. Returns ``(lif', spikes)``."""
-    deliver_local, deliver_remote = _delivery_fns(impl)
+    deliver_local, deliver_remote = _delivery_fns(impl, build_stencil(cfg))
     with jax.named_scope("dpsnn.neuron"):
         local = deliver_local(s_loc, params.w_local)
     remote = deliver_remote(s_flat, params.rem_flat, params.rem_w)

@@ -8,11 +8,11 @@ issues two kernels (``synapse_matmul``, ``lif_step``) plus the trace
 update in jnp, each round-tripping the same ``(C, N)`` membrane/trace
 state and spike slices through HBM.
 
-Remote ELL delivery is *not* in the kernel: Mosaic has no vector gather
-for a VMEM-resident table row, so the caller computes the remote
-currents with the reference's XLA gather
-(``core/network.deliver_remote_ref``) and passes them in. The kernel then
-adds them with the very expression the reference uses.
+Remote ELL delivery is *not* in the kernel: the caller computes the
+remote currents with its own kernel over bit-packed spike words
+(``kernels/ell_deliver.py``, through ``core/network.deliver_remote_packed``)
+and passes them in. The kernel then adds them with the very expression
+the reference uses.
 
 Layout. Every per-column vector is passed as ``(C, 1, N)`` so that its
 last two block dims equal the array's (the TPU (8, 128) block rule holds
@@ -185,7 +185,7 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
     * ``s_loc``              (C, N) delayed local spike frame
     * ``w_local``            (C, N, N) intra-column weights [src, tgt]
     * ``rem_cur``            (C, N) remote ELL currents
-                             (``network.deliver_remote_ref``)
+                             (``network.deliver_remote_packed``)
     * ``ext``                (C, N) external drive currents
     * ``x_pre, x_post``      (C, N) STDP traces (with ``scfg``)
 

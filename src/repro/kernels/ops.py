@@ -18,6 +18,7 @@ import functools
 
 import jax
 
+from repro.kernels import ell_deliver as _ell
 from repro.kernels import fused_step as _fused
 from repro.kernels import lif_step as _lif
 from repro.kernels import stdp_update as _stdp
@@ -53,10 +54,12 @@ def _resolved(kernel):
     return call
 
 
+ell_deliver = _resolved(_ell.ell_deliver)
+pack_spikes = _ell.pack_spikes
 fused_step = _resolved(_fused.fused_step)
 lif_step = _resolved(_lif.lif_step)
 stdp_dense_update = _resolved(_stdp.stdp_dense_update)
 synapse_matmul = _resolved(_matmul.synapse_matmul)
 
 __all__ = ["synapse_matmul", "lif_step", "stdp_dense_update", "fused_step",
-           "pad_to", "interpret_mode"]
+           "ell_deliver", "pack_spikes", "pad_to", "interpret_mode"]

@@ -103,6 +103,34 @@ def test_synapse_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("family,n", [("gauss", N), ("gauss_exp", N),
+                                      ("gauss", 100)])
+def test_ell_deliver_compiles(one_chip, family, n):
+    """Remote delivery over packed spike words at the paper's 24x24 grid
+    (C = 576): the Gaussian stencil (K = 248) and the long-range one
+    (K = 1,028, whose whole-column blocks need more than the default
+    scoped VMEM); and a column size off the 8-row tiling, whose row loop
+    the kernel unrolls. No gather is left in the program."""
+    from repro.core.connectivity import build_stencil
+
+    cfg = dpsnn.with_family(
+        dataclasses.replace(dpsnn.GRID_24, neurons_per_column=n), family)
+    stencil = build_stencil(cfg)
+    k, n_cols = stencil.k_total, cfg.n_columns
+    if n == N:
+        assert (n_cols, k) == (576, {"gauss": 248,
+                                     "gauss_exp": 1028}[family])
+    slots = tuple(ko for (_dy, _dx, ko, _d, _p) in stencil.offsets)
+    text = _compiled_text(
+        lambda *a: ops.ell_deliver(*a, slots=slots, interpret=False),
+        _spec(one_chip, (n_cols, stencil.n_offsets, -(-n // 32)),
+              jnp.uint32),
+        _spec(one_chip, (n_cols, n, k), jnp.int32),
+        _spec(one_chip, (n_cols, n, k)))
+    assert "tpu_custom_call" in text
+    assert " gather(" not in text
+
+
 def test_lif_step_compiles(one_chip):
     vec = _spec(one_chip, (C, N))
     text = _compiled_text(
@@ -150,7 +178,6 @@ def test_distributed_step_compiles_on_2x2(mesh, compiles_kernels):
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMP = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _SCOPE = re.compile(r'metadata=\{[^}]*?op_name="[^"]*?(dpsnn\.[\w\-]+)')
-_SHAPE = re.compile(r"^\(?[a-z0-9]+\[([\d,]*)\]")
 
 
 def _instructions(text):
@@ -172,14 +199,9 @@ def _scope(rhs):
     return m.group(1) if m else None
 
 
-def _elements(rhs):
-    dims = _SHAPE.match(rhs).group(1)
-    return int(np.prod([int(d) for d in dims.split(",") if d]))
-
-
 def test_single_shard_step_scopes(one_chip, compiles_kernels):
-    """The largest gather is remote delivery, and the one kernel of a
-    static step is the neuron layer's."""
+    """Remote delivery is its layer's Mosaic kernel, with no gather left
+    in its scope, and the fused step kernel is the neuron layer's."""
     from repro.core import network as net
     from repro.core import simulation as sim
 
@@ -192,17 +214,19 @@ def test_single_shard_step_scopes(one_chip, compiles_kernels):
         net.make_step_fn(cfg, impl="pallas_fused"), params, state))
     caller = {re.search(r"calls=%?([\w.\-]+)", rhs).group(1): rhs
               for _, _, rhs in instrs if " fusion(" in rhs}
-    comp, _, rhs = max(((c, n, r) for c, n, r in instrs if " gather(" in r),
-                       key=lambda x: _elements(x[2]))
-    # the trace names the fusion that holds it, by the fusion's own scope
-    assert _scope(caller.get(comp, rhs)) == "dpsnn.remote"
-    kernels = [r for _, _, r in instrs if "tpu_custom_call" in r]
-    assert kernels and all(_scope(r) == "dpsnn.neuron" for r in kernels)
+    # a gather in a fusion counts under the fusion's own scope, as the
+    # trace names it
+    gather_scopes = {_scope(caller.get(c, r)) for c, _, r in instrs
+                     if " gather(" in r}
+    assert "dpsnn.remote" not in gather_scopes
+    kernels = sorted(_scope(r) for _, _, r in instrs
+                     if "tpu_custom_call" in r)
+    assert kernels == ["dpsnn.neuron", "dpsnn.remote"]
 
 
 def test_plastic_run_kernel_scopes(one_chip, compiles_kernels):
-    """Under STDP each Mosaic kernel is the neuron layer's (fused step)
-    or the plasticity layer's (dense STDP update)."""
+    """Under STDP each Mosaic kernel is remote delivery's, the neuron
+    layer's (fused step) or the plasticity layer's (dense STDP update)."""
     from repro.core import simulation as sim
 
     cfg = dataclasses.replace(dpsnn.GRID_24, grid_h=SMALL_GRID,
@@ -214,7 +238,7 @@ def test_plastic_run_kernel_scopes(one_chip, compiles_kernels):
                          impl="pallas_fused").compile().as_text()
     kernels = [_scope(r) for _, _, r in _instructions(text)
                if "tpu_custom_call" in r]
-    assert sorted(kernels) == ["dpsnn.neuron", "dpsnn.stdp"]
+    assert sorted(kernels) == ["dpsnn.neuron", "dpsnn.remote", "dpsnn.stdp"]
 
 
 def test_distributed_step_scopes_on_2x2(mesh, compiles_kernels):
