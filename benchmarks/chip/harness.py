@@ -30,6 +30,7 @@ import cell as cellmod
 import entries
 import leastwork
 import reference
+import scopes
 import tracereduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -55,7 +56,9 @@ class _CompileCounter:
 
 class LayerContext:
     """What a per-layer reader gets: the reduced trace of the window, the
-    steps in it, the least work per step and the chip's peaks."""
+    steps in it, the least work per step and the chip's peaks; ``scopes``,
+    set where the window's program is known, maps each of its ops to its
+    named scope (``scopes.op_scopes``)."""
 
     def __init__(self, red, steps, work, peak):
         self.red, self.steps, self.work, self.peak = red, steps, work, peak
@@ -185,13 +188,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
 def layer_metrics(cell, net, entry, profile, steps, c_start, c_end):
     """The cell's per-layer metrics from the window's trace; also the
     device's busy and window seconds and the breakdown."""
-    kinds = tracereduce.op_kinds(entry.exe.as_text())
-    red = tracereduce.reduce(profile, kinds)
+    text = entry.exe.as_text()
+    red = tracereduce.reduce(profile, tracereduce.op_kinds(text))
     st = reference.stencil(net)
     work = leastwork.step_work(net, st.k_total, len(st.offsets), steps,
                                c_end[1] - c_start[1], c_end[0] - c_start[0])
     ctx = LayerContext(red, steps, work,
                        leastwork.peaks(entry.device_kind))
+    ctx.scopes = scopes.op_scopes(text)
     metrics = {}
     for m in cell.per_layer:
         value = read_layer_metric(m["name"], ctx)
@@ -200,6 +204,7 @@ def layer_metrics(cell, net, entry, profile, steps, c_start, c_end):
     busy = [tracereduce.busy(d) for d in red.devices]
     log(phase="layers", steps=steps, work_per_step=work._asdict(),
         busy_s_per_chip=busy, notes=ctx.notes,
+        scope_ms=scopes.breakdown(red, ctx.scopes, steps),
         kinds={k: sum(1 for o in red.devices[0].ops if o.kind == k)
                for k in (tracereduce.KERNEL, tracereduce.GATHER,
                          tracereduce.COLLECTIVE, tracereduce.OTHER)})

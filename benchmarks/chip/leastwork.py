@@ -23,9 +23,12 @@ recurrent events are that counter less the drive's expected arrivals
 (``N * c_ext * nu_ext * dt``), which the realized Poisson draws match to
 about 0.01 % of the recurrent events at the paper's sizes.
 
-``Work.local`` is the share that the Pallas kernels (local delivery, the
-neuron update, the dense STDP rule) do; the remote events, gathered by XLA
-outside the kernels, are the rest.
+``StepWork.kernels`` is the share that the Pallas kernels do: delivery of
+every recurrent event, local (the fused column kernel) and remote (the ELL
+delivery kernel), the neuron update and the dense local STDP rule. It
+counts events (spikes times their synapses), not the synapses a kernel
+happens to read. The remote STDP rule and, on a mesh, the rebuild of the
+params run in XLA outside the kernels and are left out of it.
 """
 from __future__ import annotations
 
@@ -48,7 +51,7 @@ class Work(NamedTuple):
 class StepWork(NamedTuple):
     """Least work of one step on average over a window."""
     step: Work
-    local: Work
+    kernels: Work
 
 
 _ITEMSIZE = {"float64": 8, "float32": 4, "bfloat16": 2, "float16": 2}
@@ -72,8 +75,7 @@ def step_work(net: dict, k_total: int, n_offsets: int, steps: int,
     dt_s = net["neuron"]["dt_ms"] * 1e-3
     ext = neurons * net["c_ext"] * net["nu_ext_hz"] * dt_s * steps
     recurrent = max(0.0, d_events - ext)
-    remote = d_spikes * k_total
-    local = max(0.0, recurrent - remote)
+    local = max(0.0, recurrent - d_spikes * k_total)
     wb = _itemsize(net["weight_dtype"])
     syn_b = wb + index_bytes(n, n_offsets)
     sb = _itemsize(net["dtype"])
@@ -85,19 +87,22 @@ def step_work(net: dict, k_total: int, n_offsets: int, steps: int,
     flops_neuron = NEURON_UPDATE_FLOPS * neurons * steps
     step = Work(flops=d_events + flops_neuron,
                 bytes=recurrent * syn_b + neurons * steps * state_b)
-    loc = Work(flops=local + flops_neuron + 2 * neurons * steps,
-               bytes=local * syn_b + neurons * steps * state_b)
+    # the kernels add each recurrent event and, once a neuron, the drive's
+    # current, which XLA computes from the arrivals
+    kern = Work(flops=recurrent + flops_neuron + neurons * steps,
+                bytes=recurrent * syn_b + neurons * steps * state_b)
     if net["stdp"]:
         # synapses out of the spiking neurons (counted by the events) and
-        # into them (their mean fan-in), excitatory sources only
+        # into them (their mean fan-in), excitatory sources only; the
+        # kernels' share is the local rule's
         touched = exc * (recurrent + d_spikes * (local_fanin + k_total))
         touched_local = exc * (local + d_spikes * local_fanin)
         step = Work(step.flops + STDP_FLOPS * touched,
                     step.bytes + 2 * wb * touched)
-        loc = Work(loc.flops + STDP_FLOPS * touched_local,
-                   loc.bytes + 2 * wb * touched_local)
+        kern = Work(kern.flops + STDP_FLOPS * touched_local,
+                    kern.bytes + 2 * wb * touched_local)
     return StepWork(step=Work(step.flops / steps, step.bytes / steps),
-                    local=Work(loc.flops / steps, loc.bytes / steps))
+                    kernels=Work(kern.flops / steps, kern.bytes / steps))
 
 
 def peaks(device_kind: str) -> dict:

@@ -1,6 +1,6 @@
 """Device time of gather operations (XLA gathers and fusions holding one:
-remote ELL delivery, and under STDP the remote pre-trace gather) per
-step, on the chip with the most (ms/step)."""
+under STDP the remote pre-trace gather, on a mesh the params rebuild's
+sign lookup) per step, on the chip with the most (ms/step)."""
 from tracereduce import GATHER, kind_time
 
 

@@ -1,6 +1,6 @@
 """Device time of the Mosaic kernels (custom calls to ``tpu_custom_call``:
-the fused column step, under STDP also the dense STDP update) per step,
-on the chip with the most (ms/step)."""
+the fused column step, the remote ELL delivery, under STDP also the dense
+STDP update) per step, on the chip with the most (ms/step)."""
 from tracereduce import KERNEL, kind_time
 
 

@@ -5,8 +5,15 @@ The reference imports nothing of the program. It reads the network's
 numbers from the configuration file (``Cell.network``) and regenerates
 what the program generates from them, with the same random streams: the
 dense intra-column weights, the remote fan-in lists and their weights,
-and the Poisson drive. It is written for clarity, in float32, one block
-of target columns at a time so that it fits beside the program's state.
+and the Poisson drive. The weights are stored as the configuration says
+(``weight_dtype``): each is rounded once from float32 to that type, as the
+program rounds it, so a network of bfloat16 synapses is compared with its
+own weights and not with the float32 ones they were rounded from. It is
+written for clarity and computes in float32, one block of target columns
+at a time so that it fits beside the program's state. Under STDP the
+weights a call starts from are the program's own; the reference does not
+model a rounding after each update, so a plastic network is compared only
+at float32 weights.
 
 What it takes from the program is the state a compared call starts from
 (membrane potentials, adaptation, refractory counters, STDP traces and
@@ -28,7 +35,7 @@ compared (each against its own limit, ``limits/<workload>.json``):
   and inhibitory synapses, against their regenerated values).
 
 ``control_chunk`` is the control: the same reference computed in bfloat16
-(state and weights), put in the program's place.
+(state, weights and arithmetic), put in the program's place.
 """
 from __future__ import annotations
 
@@ -299,8 +306,11 @@ def _compare_block(net_key, k, block, col0, t_a, frames, frame0, v_a, c_a,
     cols = col0 + jnp.arange(block, dtype=jnp.int32)
     rows, xs = cols // gw, cols % gw
     take = lambda x: jax.lax.dynamic_slice_in_dim(x, col0, block, axis=0)
+    # the weights rounded once to the configuration's weight_dtype, as the
+    # program stores them; the arithmetic stays float32
     wl, rem_src, rw, outdeg, wl0, rw0 = _block_synapses(
-        net, st, cols, jnp.float32)
+        net, st, cols, jnp.dtype(net["weight_dtype"]))
+    wl, rw = wl.astype(jnp.float32), rw.astype(jnp.float32)
     v, c, r = take(v_a), take(c_a), take(r_a)
     stdp = plastic is not None
     if stdp:
@@ -365,12 +375,17 @@ def block_size(n_columns: int, neurons: int, k_total: int) -> int:
 def compare(net: dict, a: Snapshot, b: Snapshot, devices, k: int) -> dict:
     """Every compared number of the call of ``k`` steps from ``a`` to
     ``b``."""
+    stdp = net["stdp"]
+    if stdp and net["weight_dtype"] != "float32":
+        raise ValueError(
+            f"weight_dtype {net['weight_dtype']!r}: a plastic network is "
+            "compared at float32 weights only (the reference does not round "
+            "the weights after each update)")
     st = stencil(net)
     frames, frame0 = chunk_frames(a, b, st.max_delay, k)
     n_cols = net["grid_h"] * net["grid_w"]
     blk = block_size(n_cols, net["neurons_per_column"], st.k_total)
     key = net_key(net)
-    stdp = net["stdp"]
     if stdp and k != 1:
         raise ValueError("a plastic cell is compared one step per call: the "
                          "remote rule reads the previous step's traces")

@@ -7,7 +7,7 @@ The program wraps each layer of its step in one ``jax.named_scope`` named
 ``dpsnn.drive``           Poisson variates and drive currents
 ``dpsnn.ring``            delayed spike table and history-ring writes
 ``dpsnn.halo``            halo exchange: packing, collectives, unpacking
-``dpsnn.remote``          remote ELL gather and its weighting
+``dpsnn.remote``          spike packing and the remote ELL delivery
 ``dpsnn.neuron``          local delivery and the LIF+SFA update
 ``dpsnn.stdp``            pre-trace table, pre-trace gather, STDP kernel
 ``dpsnn.params``          synapse generation (where a call rebuilds them)
@@ -15,12 +15,14 @@ The program wraps each layer of its step in one ``jax.named_scope`` named
 
 The compiled HLO keeps the scope in each instruction's ``op_name``
 metadata, among JAX's own components
-(``jit(run)/while/body/closed_call/dpsnn.remote/jit(take_along_axis)/
+(``jit(run)/while/body/closed_call/dpsnn.stdp/jit(take_along_axis)/
 gather``). A fusion carries metadata of its own, which XLA takes from its
 root; that is the one the trace's op is named by. Counters, checksums,
 the integrity guard and copies carry no scope, nor do ops that XLA adds
 with no ``op_name`` (on a TPU, the loop that lays out a gather's index
-array); they count as unscoped.
+array); they count as unscoped. The readers ``metrics/<layer>_ms.py`` and
+``metrics/unscoped_ms.py`` report ``per_step_ms`` of one scope each; the
+harness logs ``breakdown`` on its ``phase="layers"`` line.
 """
 from __future__ import annotations
 

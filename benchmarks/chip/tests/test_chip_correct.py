@@ -137,3 +137,70 @@ def test_mesh_without_its_halo_exchange_is_not_correct(monkeypatch):
     out = _run("g48-mesh4")
     assert not out["correct"], out["checks"]
     assert "v_gap_mV" in _failed(out)
+
+
+# ---------------------------------------------------------------------------
+# Synapses stored in bfloat16 (the configuration's ``weight_dtype``)
+# ---------------------------------------------------------------------------
+
+def _weights(cell, dtype):
+    """``cell`` with its synapses stored in ``dtype``."""
+    net = {**cell.config["network"], "weight_dtype": dtype}
+    return cell._replace(config={**cell.config, "network": net})
+
+
+def test_bfloat16_synapses_pass_at_the_float32_level():
+    """The reference rounds the weights as the program stores them, so the
+    gap left is the float32 arithmetic's."""
+    cell = _weights(small_cell("g24-static"), "bfloat16")
+    out = harness.run_cell(cell, SEED, 0.0, False, jax.devices("cpu")[:1],
+                           0.0)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["v_gap_mV"]["value"] <= 1e-4
+    assert out["checks"]["spike_flips"]["value"] == 0
+    assert out["checks"]["event_gap"]["value"] == 0
+
+
+def test_bfloat16_synapses_control_fails():
+    cell = _weights(small_cell("g24-static"), "bfloat16")
+    rows = []
+    calibrate.readings(cell, [11, 12], {11, 12}, 1, jax.devices("cpu")[:1],
+                       rows.append)
+    assert {r["side"] for r in rows} == {"program", "control"}
+    for row in rows:
+        failed = [k for k, lim in cell.limits.items() if not row[k] <= lim]
+        assert bool(failed) == (row["side"] == "control"), row
+
+
+def test_float32_synapses_under_a_bfloat16_label_read_far_apart():
+    """The fault this comparison guards against: a reference whose weights
+    are not the program's. A float32-synapse run compared as if its
+    synapses were bfloat16 reads a gap far above the matched comparison."""
+    import cell as cellmod
+    import entries
+
+    cell = small_cell("g24-static")
+    devices = jax.devices("cpu")[:1]
+    entry = entries.make(cell, cellmod.program_config(cell), devices)
+    carry = entry.build(*entries.seed_words(SEED))
+    entry.compile(carry)
+    for _ in range(2):
+        prev = carry
+        carry, done = entry.call(carry)
+        done.block_until_ready()
+    a = entry.snapshot(entry.drop_params(prev))
+    b = entry.snapshot(entry.drop_params(carry))
+    k = cell.steps_per_call
+    matched = reference.compare(cell.network, a, b, devices, k)
+    labelled = reference.compare(_weights(cell, "bfloat16").network, a, b,
+                                 devices, k)
+    assert matched["v_gap_mV"] <= 1e-4
+    assert labelled["v_gap_mV"] >= 100 * max(matched["v_gap_mV"], 1e-7)
+
+
+def test_plastic_bfloat16_synapses_are_refused():
+    """The reference does not round a plastic weight after each update, so
+    a plastic network is compared at float32 weights only."""
+    net = _weights(small_cell("g24-stdp"), "bfloat16").network
+    with pytest.raises(ValueError, match="weight_dtype"):
+        reference.compare(net, None, None, jax.devices("cpu")[:1], 1)

@@ -34,15 +34,16 @@ def test_static_step_by_hand():
     neurons = 16 * 64
     ext = neurons * 540 * 3.0 * 1e-3 * 2        # expected drive arrivals
     recurrent = 50_000 - ext
-    local = recurrent - 100 * 24
     state = 2 * (2 * 4 + 1) + 1 / 8             # v, c, refrac r+w; spike bit
     assert w.step.bytes == pytest.approx(
         (recurrent * (4 + 2) + 2 * neurons * state) / 2)
-    assert w.local.bytes == pytest.approx(
-        (local * (4 + 2) + 2 * neurons * state) / 2)
+    # the kernels deliver every recurrent event, the 100 * 24 remote ones
+    # (the ELL delivery kernel) among them
+    assert w.kernels.bytes == pytest.approx(
+        (recurrent * (4 + 2) + 2 * neurons * state) / 2)
     assert w.step.flops == pytest.approx((50_000 + 10 * neurons * 2) / 2)
-    assert w.local.flops == pytest.approx(
-        (local + 10 * neurons * 2 + 2 * neurons * 2) / 2)
+    assert w.kernels.flops == pytest.approx(
+        (recurrent + 10 * neurons * 2 + neurons * 2) / 2)
 
 
 def test_plastic_step_adds_touched_synapses_by_hand():
@@ -57,8 +58,11 @@ def test_plastic_step_adds_touched_synapses_by_hand():
     touched_local = 0.8 * (local + 100 * 50)
     assert w.step.bytes == pytest.approx(
         recurrent * 6 + neurons * state + 2 * 4 * touched)
-    assert w.local.bytes == pytest.approx(
-        local * 6 + neurons * state + 2 * 4 * touched_local)
+    # remote events are the kernels'; the remote rule (XLA) is not
+    assert w.kernels.bytes == pytest.approx(
+        recurrent * 6 + neurons * state + 2 * 4 * touched_local)
+    assert w.kernels.flops == pytest.approx(
+        recurrent + 10 * neurons + neurons + 4 * touched_local)
 
 
 def test_least_time_names_its_bound():

@@ -229,3 +229,57 @@ def test_recorded_stdp_trace_reduces_by_scope(recorded_stdp):
         harness.read_layer_metric("gather_ms", ctx), rel=1e-12)
     assert sum(kernels) == pytest.approx(
         harness.read_layer_metric("kernel_ms", ctx), rel=1e-6)
+
+
+# the scope readers (``metrics/<layer>_ms.py``) on the recorded trace: the
+# scope each reads, and its ms in that one plastic step by the hand sums
+# above (None: no op of the scope ran)
+READERS = {"remote_ms": ("dpsnn.remote", 2055.319388 + 15.92308),
+           "stdp_ms": ("dpsnn.stdp", 2055.391199 + 38.076761 + 11.874487),
+           "neuron_ms": ("dpsnn.neuron", 4.834336 + 0.03487),
+           "drive_ms": ("dpsnn.drive", 0.368985),
+           "ring_ms": ("dpsnn.ring", 0.343388),
+           "unscoped_ms": (None, 58.533277),
+           "rebuild_ms": ("dpsnn.params", None),
+           "halo_ms": ("dpsnn.halo", None)}
+
+
+def _recorded_ctx(recorded_stdp):
+    profile, kinds, sc = recorded_stdp
+    ctx = harness.LayerContext(tr.reduce(profile, kinds), 1, None, None)
+    ctx.scopes = sc
+    return ctx
+
+
+@pytest.mark.parametrize("metric", sorted(READERS))
+def test_recorded_stdp_trace_through_the_scope_readers(recorded_stdp,
+                                                       metric):
+    profile, kinds, sc = recorded_stdp
+    ctx = _recorded_ctx(recorded_stdp)
+    scope, want = READERS[metric]
+    got = harness.read_layer_metric(metric, ctx)
+    if want is None:
+        assert got is None
+        return
+    raw = _raw_by_scope(profile, kinds, sc, *ctx.red.window)
+    assert got == pytest.approx(sum(v for (s, k), v in raw.items()
+                                    if s == scope and k != tr.CONTROL),
+                                rel=1e-12)
+    assert got == pytest.approx(want, rel=1e-6)
+
+
+def test_scope_readers_add_up_to_every_op_but_the_loops(recorded_stdp):
+    """What the readers report in g24-stdp is every non-loop op of the
+    step, each once."""
+    import cell as cellmod
+
+    ctx = _recorded_ctx(recorded_stdp)
+    listed = {m["name"] for m in cellmod.load_cell("g24-stdp").per_layer}
+    assert {"remote_ms", "stdp_ms", "neuron_ms", "drive_ms", "ring_ms",
+            "unscoped_ms"} <= listed
+    assert not {"rebuild_ms", "halo_ms"} & listed
+    got = [harness.read_layer_metric(m, ctx) for m in READERS
+           if m in listed]
+    dev = ctx.red.devices[0]
+    total = sum(o.end - o.start for o in dev.ops if o.kind != tr.CONTROL)
+    assert sum(got) == pytest.approx(1e3 * total, rel=1e-12)

@@ -80,7 +80,11 @@ def test_recorded_trace_layer_metrics(recorded):
                                leastwork.peaks("TPU v5 lite"))
     got = {m["name"]: harness.read_layer_metric(m["name"], ctx)
            for m in cell.per_layer}
-    assert got["gather_ms"] == pytest.approx(4110.80575 / STEPS, rel=1e-9)
+    # the cell lists no gather_ms since its delivery left the gather; the
+    # reader still reduces the gather of this earlier program
+    assert "gather_ms" not in got
+    assert harness.read_layer_metric("gather_ms", ctx) == pytest.approx(
+        4110.80575 / STEPS, rel=1e-9)
     busy = tr.busy(red.devices[0])
     assert got["idle_share"] == pytest.approx(
         100 * (1 - busy / ctx.window_s))

@@ -6,7 +6,8 @@ program carry, with no change to the program: a Mosaic kernel is a
 whose computation holds one; a collective is a collective opcode
 (``collective-permute``, ``all-reduce``, ... and their ``-start``/``-done``
 halves) or a fusion holding one. The compiled HLO text of the window's
-program maps each operation's name to its kind.
+program maps each operation's name to its kind; the same text maps it to
+the program's named scope, its layer (``scopes.py``).
 
 On a TPU the trace's ``XLA Ops`` line of each ``/device:TPU:<i>`` plane
 holds one event per executed operation, named by its HLO instruction text
