@@ -1079,7 +1079,7 @@ def dist_step(cfg: DPSNNConfig, params: NetworkParams, state: DistState, *,
                 state.hist_ext, ext_frame, (state.t - 1) % d_slots, axis=0)
 
     # exact int32 counts, as in network.step_single (core/counters.py)
-    k_tot = params.rem_w.shape[-1]
+    k_tot = stencil.k_total
     n_spikes = spikes.astype(jnp.int32)
     events = ((n_spikes * (params.local_outdeg.astype(jnp.int32) + k_tot)
                ).sum() + ext_counts.sum())

@@ -30,6 +30,7 @@ not nest, so each op has at most one; counters and the guard have none.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -43,10 +44,66 @@ from repro.core.neuron import LIFState, lif_init, lif_sfa_step
 
 
 class NetworkParams(NamedTuple):
-    w_local: jax.Array      # (C, N, N)
-    rem_flat: jax.Array     # (C, N, K) gather idx into (O*N,) table
-    rem_w: jax.Array        # (C, N, K)
+    w_local: jax.Array      # (C, N, NW), NW = N or its lane_width
+    rem_flat: jax.Array     # (C, N, KW) idx into (O*N,) table, KW likewise
+    rem_w: jax.Array        # (C, N, KW)
     local_outdeg: jax.Array  # (C, N) for synaptic-event accounting
+
+
+LANES, SUBLANES = 128, 8
+
+
+def _tiled_size(shape: tuple[int, ...], order: tuple[int, ...],
+                itemsize: int) -> int:
+    """Elements a TPU's tiled layout of ``shape`` holds with its axes in
+    ``order`` (major to minor): the minor axis rounded up to whole
+    128-lane tiles, the second-minor to 8 sublanes (1, 2 or 4 kept, and
+    at least the rows one 32-bit word packs)."""
+    up = lambda x, m: -(-x // m) * m
+    *major, second, minor = (shape[i] for i in order)
+    rows = second if second in (1, 2, 4) else up(second, SUBLANES)
+    size = up(minor, LANES) * max(rows, 4 // itemsize)
+    for d in major:
+        size *= d
+    return size
+
+
+def tpu_row_major(shape: tuple[int, ...], itemsize: int = 4) -> bool:
+    """Whether a TPU lays an array of ``shape`` out row-major by default.
+
+    It takes the order of the axes whose tiles hold the fewest elements,
+    the row-major order on a tie (tests/test_tpu_compile.py checks this
+    against the TPU compiler's own default layouts)."""
+    row = _tiled_size(shape, tuple(range(len(shape))), itemsize)
+    return all(row <= _tiled_size(shape, order, itemsize)
+               for order in itertools.permutations(range(len(shape))))
+
+
+def lane_width(shape: tuple[int, int, int], *itemsizes: int) -> int:
+    """Stored width of the minor axis of a static ``(C, N, width)``
+    synapse table of elements of ``itemsizes`` bytes (4 if none given).
+
+    The kernels read one column's ``(N, width)`` block in place, so the
+    table has to be laid out row-major on the chip. A TPU lays it out
+    otherwise where another order of the axes pads less: the columns along
+    the lanes when C is a whole number of lane tiles and ``width`` is not
+    (2,304 columns of 1,240 targets or 248 slots), the targets there for
+    576 columns of K = 1,028 slots; every call then copies the whole
+    table into the kernels' layout. Such a table is stored ``width``
+    rounded up to whole lanes where that makes it row-major and adds at
+    most an eighth to it (the kernels read every stored entry each step,
+    so a narrow table, a small grid's K = 24, keeps its width and the
+    copy); the added entries are absent synapses (weight 0). Any other
+    table is stored at its own width."""
+    c, n, width = shape
+    wide = -(-width // LANES) * LANES
+    if 8 * (wide - width) > width:
+        return width
+    for size in itemsizes or (4,):
+        if (not tpu_row_major(shape, size)
+                and tpu_row_major((c, n, wide), size)):
+            return wide
+    return width
 
 
 class NetworkState(NamedTuple):
@@ -59,18 +116,71 @@ class NetworkState(NamedTuple):
     guard: Optional[Any] = None  # GuardState when cfg.guard.enabled
 
 
+# Bytes of the (block, N, N) float32 generation intermediates that one
+# block of build_params may take.
+BUILD_BUDGET = 4 << 30
+
+
+def _column_params(cfg: DPSNNConfig, stencil: StencilSpec,
+                   col_ids: jax.Array, nw: int, kw: int) -> NetworkParams:
+    """The params of ``col_ids``, their tables ``nw`` / ``kw`` wide."""
+    n, k = cfg.neurons_per_column, stencil.k_total
+    w_local, rem_idx, rem_w = conn.generate_columns(cfg, col_ids)
+    rem_flat = conn.flat_gather_index(stencil, rem_idx, n)
+    outdeg = conn.local_out_degree(w_local).astype(jnp.float32)
+    pad = lambda x, to, value=0: jnp.pad(
+        x, ((0, 0), (0, 0), (0, to - x.shape[2])), constant_values=value)
+    if nw != n:
+        w_local = pad(w_local, nw)
+    if kw != k:
+        # absent slots of the last offset: source 0 of its column, weight 0
+        rem_flat = pad(rem_flat, kw, (stencil.n_offsets - 1) * n)
+        rem_w = pad(rem_w, kw)
+    return NetworkParams(w_local=w_local, rem_flat=rem_flat, rem_w=rem_w,
+                         local_outdeg=outdeg)
+
+
 def build_params(cfg: DPSNNConfig, col_ids: jax.Array) -> NetworkParams:
+    """The synapses of the columns ``col_ids``.
+
+    They are generated in the fewest equal blocks of columns whose
+    ``(block, N, N)`` float32 intermediates fit ``BUILD_BUDGET``, each
+    written into the result in place, so that params which fill most of
+    the chip are built without a second params-sized intermediate: the
+    paper's 24x24 grid, or a 24x24 tile of a mesh, is one block; its
+    48x48 grid four of 576 columns. Generation is deterministic per
+    global column id, so every blocking gives the same bits. A block that
+    does not divide the column count ends on a last block that overlaps
+    the one before it and rewrites those columns with the same values.
+
+    A static network's tables are stored at :func:`lane_width`; a plastic
+    network's, which the STDP rule rewrites, at their own widths."""
     stencil = build_stencil(cfg)
+    nc, n, k = col_ids.shape[0], cfg.neurons_per_column, stencil.k_total
+    nw, kw = n, k
+    if not cfg.stdp:
+        size = jnp.dtype(cfg.weight_dtype).itemsize
+        nw, kw = lane_width((nc, n, n), size), lane_width((nc, n, k), size, 4)
+    fit = max(1, BUILD_BUDGET // (4 * n * n))
+    block = -(-nc // -(-nc // fit))
     with jax.named_scope("dpsnn.params"):
-        w_local, rem_idx, rem_w = conn.generate_columns(cfg, col_ids)
-        rem_flat = conn.flat_gather_index(stencil, rem_idx,
-                                          cfg.neurons_per_column)
-        return NetworkParams(
-            w_local=w_local,
-            rem_flat=rem_flat,
-            rem_w=rem_w,
-            local_outdeg=conn.local_out_degree(w_local).astype(jnp.float32),
-        )
+        if block == nc:
+            return _column_params(cfg, stencil, col_ids, nw, kw)
+        out = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(lambda ids: _column_params(cfg, stencil, ids,
+                                                      nw, kw), col_ids))
+
+        def put(i, acc):
+            c0 = jnp.minimum(i * block, nc - block)
+            part = _column_params(
+                cfg, stencil, jax.lax.dynamic_slice_in_dim(col_ids, c0, block),
+                nw, kw)
+            return jax.tree_util.tree_map(
+                lambda a, p: jax.lax.dynamic_update_slice_in_dim(a, p, c0, 0),
+                acc, part)
+
+        return jax.lax.fori_loop(0, -(-nc // block), put, out)
 
 
 def init_state(cfg: DPSNNConfig, col_ids: jax.Array,
@@ -121,16 +231,19 @@ def init_state(cfg: DPSNNConfig, col_ids: jax.Array,
 # ---------------------------------------------------------------------------
 
 def deliver_local_ref(spikes: jax.Array, w_local: jax.Array) -> jax.Array:
-    """(C,N) x (C,N,N) -> (C,N): batched MXU matmul over columns.
+    """(C,N) x (C,N,NW) -> (C,N): batched MXU matmul over columns (targets
+    past N, a lane-padded table's, are dropped).
 
     ``Precision.HIGHEST`` keeps the reference f32 on the chip too (a TPU
     multiplies f32 operands in one bf16 pass at default precision); on
     the CPU it changes nothing."""
-    return jnp.einsum(
+    cur = jnp.einsum(
         "cs,cst->ct", spikes, w_local,
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(spikes.dtype)
+    n = spikes.shape[1]
+    return cur if cur.shape[1] == n else cur[:, :n]
 
 
 def deliver_remote_ref(s_flat: jax.Array, rem_flat: jax.Array,
@@ -157,7 +270,10 @@ def deliver_remote_packed(s_flat: jax.Array, rem_flat: jax.Array,
     the same currents as :func:`deliver_remote_ref`, read from the spike
     table packed 32 spikes to a word, with no gather."""
     from repro.kernels import ops
-    slots = tuple(k for (_dy, _dx, k, _d, _p) in stencil.offsets)
+    slots = [k for (_dy, _dx, k, _d, _p) in stencil.offsets]
+    # a lane-padded table's absent slots belong to the last offset
+    slots[-1] += rem_flat.shape[2] - stencil.k_total
+    slots = tuple(slots)
     with jax.named_scope("dpsnn.remote"):
         words = ops.pack_spikes(s_flat, stencil.n_offsets)
         return ops.ell_deliver(words, rem_flat, rem_w, slots=slots,
@@ -322,7 +438,7 @@ def step_single(cfg: DPSNNConfig, params: NetworkParams,
     #    plus (statistically exact for ELL) K_tot remote targets; external
     #    events count each Poisson arrival.
     #    Counted in int32 (exact) into the counters.py running totals.
-    k_tot = params.rem_w.shape[-1]
+    k_tot = stencil.k_total
     n_spikes = spikes.astype(jnp.int32)
     events = ((n_spikes * (params.local_outdeg.astype(jnp.int32) + k_tot)
                ).sum() + ext_counts.sum())

@@ -7,12 +7,10 @@ for a halo exchange.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-
-from typing import Optional
 
 from repro.configs.base import DPSNNConfig
 from repro.core import counters
@@ -28,7 +26,7 @@ class SimResult(NamedTuple):
     events: jax.Array        # total synaptic events (paper metric)
     spikes: jax.Array         # total spikes
     rate_trace: jax.Array     # (T,) per-step population rate (Hz)
-    params: Optional[NetworkParams] = None  # final params (plastic under STDP)
+    params: Optional[NetworkParams] = None  # final plastic params (STDP only)
 
 
 def build(cfg: DPSNNConfig, *, seed=None):
@@ -55,7 +53,9 @@ def run(cfg: DPSNNConfig, params: NetworkParams, state: NetworkState,
     (local outer products + remote ELL gather through the previous step's
     pre-trace table — the same one-step-lag semantics the distributed
     halo exchange delivers, DESIGN.md §Plasticity), and the final plastic
-    params are returned in ``SimResult.params``.
+    params are returned in ``SimResult.params``. Static params stay out
+    of the carry: the loop reads the caller's buffers, the program
+    writes no copy of them, and ``SimResult.params`` is None.
 
     ``seed``/``nu_scale`` (traced, optional) select a per-tenant Poisson
     drive stream / stimulus intensity — the single-tenant reference for
@@ -67,11 +67,10 @@ def run(cfg: DPSNNConfig, params: NetworkParams, state: NetworkState,
     is_inh = neuron_types(cfg)
 
     def body(carry, _):
-        p0, s0 = carry
+        p0, s0 = carry if cfg.stdp else (params, carry)
         s1 = net.step_single(cfg, p0, s0, stencil=stencil, grid_hw=grid_hw,
                              col_ids=col_ids, impl=impl, seed=seed,
                              nu_scale=nu_scale)
-        p1 = p0
         if cfg.stdp:
             spikes = jnp.take(s1.hist, s0.t % s0.hist.shape[0], axis=0)
             table = plast.pre_trace_table(s0.stdp.x_pre, stencil, grid_hw)
@@ -89,10 +88,11 @@ def run(cfg: DPSNNConfig, params: NetworkParams, state: NetworkState,
         step_rate = step_spikes.sum() / (
             s0.hist.shape[1] * s0.hist.shape[2]
         ) / (cfg.neuron.dt_ms * 1e-3)
-        return (p1, s1), step_rate
+        return ((p1, s1) if cfg.stdp else s1), step_rate
 
-    (final_params, final), rate_trace = jax.lax.scan(
-        body, (params, state), None, length=n_steps)
+    carry, rate_trace = jax.lax.scan(
+        body, (params, state) if cfg.stdp else state, None, length=n_steps)
+    final_params, final = carry if cfg.stdp else (None, carry)
     sim_seconds = n_steps * cfg.neuron.dt_ms * 1e-3
     n_neurons = state.hist.shape[1] * state.hist.shape[2]
     spikes = counters.value(final.spike_count)

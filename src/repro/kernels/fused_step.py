@@ -16,8 +16,9 @@ the reference uses.
 
 Layout. Every per-column vector is passed as ``(C, 1, N)`` so that its
 last two block dims equal the array's (the TPU (8, 128) block rule holds
-for any N); the weights are ``(C, N, N)`` with one whole column per block.
-Nothing is padded along N. Grid ``(C / BLK_C,)`` over column tiles; per
+for any N); the weights are ``(C, N, NW)`` with one whole column per block
+(NW = N, or N rounded up to whole lanes for a lane-padded table).
+The kernel pads nothing itself. Grid ``(C / BLK_C,)`` over column tiles; per
 tile the kernel
 
 1. accumulates the local delivery ``spikes @ w_local`` in 128-row source
@@ -70,22 +71,23 @@ VMEM_TILE_BUDGET = 4 << 20   # soft budget for one tile's weight blocks
 VMEM_HEADROOM = 4 << 20
 
 
-def column_block(nc: int, n: int, itemsize: int = 4) -> int:
+def column_block(nc: int, block_bytes: int) -> int:
     """Columns per grid tile: the largest divisor of ``nc`` (capped at
-    ``MAX_BLK_C``) whose (N, N) weight blocks fit the soft VMEM budget;
-    at least 1, which is what the paper's N=1240 columns get."""
-    fit = max(1, min(MAX_BLK_C, VMEM_TILE_BUDGET // (n * n * itemsize)))
+    ``MAX_BLK_C``) whose weight blocks of ``block_bytes`` each fit the
+    soft VMEM budget; at least 1, which is what the paper's N=1240
+    columns get."""
+    fit = max(1, min(MAX_BLK_C, VMEM_TILE_BUDGET // block_bytes))
     return max(d for d in range(1, fit + 1) if nc % d == 0)
 
 
-def vmem_limit(blk_c: int, n: int, itemsize: int = 4) -> int:
-    """Scoped-VMEM limit for one tile: the weight block double-buffered
-    plus ``VMEM_HEADROOM``. At N=1240 this is 16.3 MB, just above the
-    16 MiB v5e default, which is why the kernel states it explicitly."""
-    return 2 * blk_c * n * n * itemsize + VMEM_HEADROOM
+def vmem_limit(blk_c: int, block_bytes: int) -> int:
+    """Scoped-VMEM limit for one tile: the weight blocks double-buffered
+    plus ``VMEM_HEADROOM``. At N=1240 in f32 this is 16.3 MB, just above
+    the 16 MiB v5e default, which is why the kernel states it explicitly."""
+    return 2 * blk_c * block_bytes + VMEM_HEADROOM
 
 
-def _make_kernel(ncfg: NeuronConfig, n: int, with_stdp: bool,
+def _make_kernel(ncfg: NeuronConfig, n: int, nw: int, with_stdp: bool,
                  guard: GuardConfig | None = None, blk_c: int = 1):
     # Python-float constants close over the kernel exactly as they appear
     # in core/neuron.lif_sfa_step (weak-typed f32 promotion, identical
@@ -107,6 +109,11 @@ def _make_kernel(ncfg: NeuronConfig, n: int, with_stdp: bool,
             vo_ref, co_ref, ro_ref, so_ref = rest
 
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # f32 weights contract at f32 precision; bf16 weights times 0/1
+        # spikes are exact products at the MXU's native precision (Mosaic
+        # refuses an fp32 contraction of bf16 operands)
+        precision = (jax.lax.Precision.HIGHEST if w_ref.dtype == jnp.float32
+                     else jax.lax.Precision.DEFAULT)
         # block-event skip: a silent (column, source slice) contributes
         # nothing, so its MXU work is skipped
         for j in range(blk_c):
@@ -118,16 +125,17 @@ def _make_kernel(ncfg: NeuronConfig, n: int, with_stdp: bool,
                     acc_ref[j] += jax.lax.dot_general(
                         s.astype(w_ref.dtype), w_ref[j, s0:s0 + sz, :],
                         (((1,), (0,)), ((), ())),
-                        precision=jax.lax.Precision.HIGHEST,
+                        precision=precision,
                         preferred_element_type=jnp.float32,
-                    )                                      # (1, N)
+                    )                                      # (1, NW)
 
         decay_v, decay_c, gain = par_ref[0], par_ref[1], par_ref[2]
         dtype = v_ref.dtype
         # local delivery closes: f32 accumulator -> state dtype
         # (deliver_local_ref's single einsum->astype cast), then the
         # remote and external currents in the ref's order
-        cur = acc_ref[...].astype(dtype)
+        acc = acc_ref[...] if nw == n else acc_ref[:, :, :n]
+        cur = acc.astype(dtype)
         cur = cur + rem_ref[...]
         cur = cur + ext_ref[...]
 
@@ -183,7 +191,9 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
 
     * ``v, c, refrac``       (C, N) LIF state
     * ``s_loc``              (C, N) delayed local spike frame
-    * ``w_local``            (C, N, N) intra-column weights [src, tgt]
+    * ``w_local``            (C, N, NW) intra-column weights [src, tgt];
+                             targets past N (a lane-padded table,
+                             ``network.lane_width``) are absent
     * ``rem_cur``            (C, N) remote ELL currents
                              (``network.deliver_remote_packed``)
     * ``ext``                (C, N) external drive currents
@@ -217,8 +227,9 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
     else:
         params = jnp.stack([decay_v, decay_c, gain])
 
-    itemsize = jnp.dtype(w_local.dtype).itemsize
-    blk_c = column_block(nc, n, itemsize)
+    nw = w_local.shape[2]
+    block_bytes = n * nw * jnp.dtype(w_local.dtype).itemsize
+    blk_c = column_block(nc, block_bytes)
     vecs = [v, c, refrac]
     if with_stdp:
         vecs += [x_pre, x_post]
@@ -226,7 +237,7 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # params
         vspec,                                                 # s_loc
-        pl.BlockSpec((blk_c, n, n), lambda ci: (ci, 0, 0)),    # w_local
+        pl.BlockSpec((blk_c, n, nw), lambda ci: (ci, 0, 0)),   # w_local
         vspec, vspec,                                          # rem, ext
     ] + [vspec] * len(vecs)
     args = [params, s_loc[:, None], w_local, rem_cur[:, None],
@@ -247,16 +258,16 @@ def fused_step(ncfg: NeuronConfig, v, c, refrac, s_loc, w_local, rem_cur,
                                       lambda ci: (ci, 0, 0)))
 
     out = pl.pallas_call(
-        _make_kernel(ncfg, n, with_stdp,
+        _make_kernel(ncfg, n, nw, with_stdp,
                      guard=gcfg if with_guard else None, blk_c=blk_c),
         grid=(nc // blk_c,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((blk_c, 1, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk_c, 1, nw), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=vmem_limit(blk_c, n, itemsize)),
+            vmem_limit_bytes=vmem_limit(blk_c, block_bytes)),
         interpret=interpret,
     )(*args)
     if with_guard:

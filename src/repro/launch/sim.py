@@ -4,6 +4,9 @@
         --steps 500 [--devices 4] [--impl pallas_fused] [--pipelined] \
         [--no-compress]
 
+``--preset 48x48-bf16`` (any key of ``configs/dpsnn.GRIDS``) runs one of
+the paper's grids at its own size in place of ``--grid``/``--neurons``.
+
 On a multi-device host (XLA_FLAGS=--xla_force_host_platform_device_count=N
 or a real pod) the grid is tiled over a 2-D mesh with halo exchange;
 otherwise the single-shard reference path runs.
@@ -11,12 +14,14 @@ otherwise the single-shard reference path runs.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import DPSNNConfig
+from repro.configs.dpsnn import GRIDS
 from repro.core import exchange, metrics as M, simulation as sim
 from repro.runtime.compile_cache import enable_compile_cache
 
@@ -29,6 +34,9 @@ def parse_grid(s: str):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--grid", default="8x8")
+    ap.add_argument("--preset", choices=sorted(GRIDS),
+                    help="a paper grid (configs/dpsnn.py) in place of "
+                         "--grid and --neurons")
     ap.add_argument("--neurons", type=int, default=64)
     ap.add_argument("--steps", type=int, default=500)
     ap.add_argument("--impl", default="ref",
@@ -45,9 +53,13 @@ def main():
 
     gh, gw = parse_grid(args.grid)
     from repro.configs.base import ExchangeConfig
-    cfg = DPSNNConfig(grid_h=gh, grid_w=gw, neurons_per_column=args.neurons,
-                      stdp=args.stdp, seed=args.seed,
-                      exchange=ExchangeConfig(pipelined=args.pipelined))
+    cfg = DPSNNConfig(grid_h=gh, grid_w=gw, neurons_per_column=args.neurons)
+    if args.preset:
+        cfg = GRIDS[args.preset]
+        gh, gw = cfg.grid_h, cfg.grid_w
+    cfg = dataclasses.replace(
+        cfg, stdp=args.stdp, seed=args.seed,
+        exchange=ExchangeConfig(pipelined=args.pipelined))
     print(f"grid {gh}x{gw}, {cfg.n_neurons} neurons, "
           f"{cfg.recurrent_synapses/1e6:.1f}M recurrent synapses "
           f"({cfg.local_fanin}+{cfg.remote_fanin}/neuron), "

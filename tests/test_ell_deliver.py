@@ -3,8 +3,8 @@ interpreted on the CPU, against the reference gather
 (``core/network.deliver_remote_ref``): bitwise equal currents for column
 sizes of one word and several, on and off the 8- and 32-row tilings, the
 Gaussian stencil and the many-offset ``gauss_exp`` one, silent to
-saturated spike tables, and excitatory and inhibitory (negative)
-weights."""
+saturated spike tables, excitatory and inhibitory (negative) weights,
+stored in float32 or bfloat16."""
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +18,10 @@ from repro.core import network as net
 from repro.kernels import ops
 
 
-def _synapses(n, family, n_cols):
+def _synapses(n, family, n_cols, weight_dtype="float32"):
     cfg = dpsnn.with_family(
-        DPSNNConfig(grid_h=4, grid_w=4, neurons_per_column=n, seed=5), family)
+        DPSNNConfig(grid_h=4, grid_w=4, neurons_per_column=n, seed=5,
+                    weight_dtype=weight_dtype), family)
     stencil = conn.build_stencil(cfg)
     _, idx, w = conn.generate_columns(cfg, jnp.arange(n_cols))
     return stencil, conn.flat_gather_index(stencil, idx, n), w
@@ -31,12 +32,17 @@ def _spike_table(n_cols, width, density, seed=0):
     return (draw < density).astype(jnp.float32)
 
 
-@pytest.mark.parametrize("density", [0.0, 0.05, 1.0])
-@pytest.mark.parametrize("family", ["gauss", "gauss_exp"])
-@pytest.mark.parametrize("n", [20, 48, 100, 1240])
-def test_ell_deliver_bitwise_vs_ref(n, family, density):
+@pytest.mark.parametrize("n,family,density,weight_dtype", [
+    pytest.param(n, family, density, wd,
+                 id=f"{n}-{family}-{density}" + ("-bf16" if wd else ""))
+    for n in (20, 48, 100, 136, 1240) for family in ("gauss", "gauss_exp")
+    for density in (0.0, 0.05, 1.0) for wd in ("", "bfloat16")])
+def test_ell_deliver_bitwise_vs_ref(n, family, density, weight_dtype):
+    """N = 136 steps 8 rows at a time, off bfloat16's 16-row tile."""
     n_cols = 1 if n == 1240 else 4
-    stencil, rem_flat, rem_w = _synapses(n, family, n_cols)
+    weight_dtype = weight_dtype or "float32"
+    stencil, rem_flat, rem_w = _synapses(n, family, n_cols, weight_dtype)
+    assert rem_w.dtype == jnp.dtype(weight_dtype)
     assert bool((rem_w < 0).any()) and bool((rem_w > 0).any())
     s_flat = _spike_table(n_cols, stencil.n_offsets * n, density)
     ref = net.deliver_remote_ref(s_flat, rem_flat, rem_w)

@@ -15,6 +15,7 @@ sees the CPU here, so the step tests steer the interpret decision to
 "compile" with a monkeypatch.
 """
 import dataclasses
+import itertools
 import re
 
 import jax
@@ -72,11 +73,20 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("variant", ["static", "stdp", "guard"])
-def test_fused_step_compiles(one_chip, variant):
+@pytest.mark.parametrize("variant,wdtype,nw", [
+    pytest.param("static", jnp.float32, N, id="static"),
+    pytest.param("stdp", jnp.float32, N, id="stdp"),
+    pytest.param("guard", jnp.float32, N, id="guard"),
+    pytest.param("static", jnp.bfloat16, N, id="static-bf16"),
+    pytest.param("static", jnp.bfloat16, 1280, id="static-bf16-1280")])
+def test_fused_step_compiles(one_chip, variant, wdtype, nw):
+    """Every variant at float32 weights; bfloat16 blocks, whose 88-row
+    last source slice at N = 1,240 is off bfloat16's 16-row tile, at
+    their own width and lane-padded to 1,280 targets (the 48x48 grid on
+    one chip stores them so)."""
     vec = _spec(one_chip, (C, N))
     args = [vec, vec, _spec(one_chip, (C, N), jnp.int32), vec,
-            _spec(one_chip, (C, N, N)), vec, vec]
+            _spec(one_chip, (C, N, nw), wdtype), vec, vec]
     kw = {}
     if variant == "stdp":
         args += [vec, vec]
@@ -103,14 +113,23 @@ def test_synapse_matmul_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("family,n", [("gauss", N), ("gauss_exp", N),
-                                      ("gauss", 100)])
-def test_ell_deliver_compiles(one_chip, family, n):
+@pytest.mark.parametrize("family,n,wdtype,kw", [
+    pytest.param("gauss", N, jnp.float32, None, id="gauss-1240"),
+    pytest.param("gauss_exp", N, jnp.float32, None, id="gauss_exp-1240"),
+    pytest.param("gauss", 100, jnp.float32, None, id="gauss-100"),
+    pytest.param("gauss", N, jnp.bfloat16, None, id="gauss-1240-bf16"),
+    pytest.param("gauss", N, jnp.bfloat16, 256, id="gauss-1240-bf16-256"),
+    pytest.param("gauss_exp", N, jnp.float32, 1152,
+                 id="gauss_exp-1240-1152")])
+def test_ell_deliver_compiles(one_chip, family, n, wdtype, kw):
     """Remote delivery over packed spike words at the paper's 24x24 grid
     (C = 576): the Gaussian stencil (K = 248) and the long-range one
     (K = 1,028, whose whole-column blocks need more than the default
-    scoped VMEM); and a column size off the 8-row tiling, whose row loop
-    the kernel unrolls. No gather is left in the program."""
+    scoped VMEM); a column size off the 8-row tiling, whose row loop
+    the kernel unrolls; bfloat16 weights, whose 248-row inner steps start
+    off bfloat16's 16-row tile; and tables lane-padded to ``kw`` slots,
+    the absent ones counted with the last offset's. No gather is left in
+    the program."""
     from repro.core.connectivity import build_stencil
 
     cfg = dpsnn.with_family(
@@ -120,13 +139,15 @@ def test_ell_deliver_compiles(one_chip, family, n):
     if n == N:
         assert (n_cols, k) == (576, {"gauss": 248,
                                      "gauss_exp": 1028}[family])
-    slots = tuple(ko for (_dy, _dx, ko, _d, _p) in stencil.offsets)
+    kw = kw or k
+    slots = [ko for (_dy, _dx, ko, _d, _p) in stencil.offsets]
+    slots[-1] += kw - k
     text = _compiled_text(
-        lambda *a: ops.ell_deliver(*a, slots=slots, interpret=False),
+        lambda *a: ops.ell_deliver(*a, slots=tuple(slots), interpret=False),
         _spec(one_chip, (n_cols, stencil.n_offsets, -(-n // 32)),
               jnp.uint32),
-        _spec(one_chip, (n_cols, n, k), jnp.int32),
-        _spec(one_chip, (n_cols, n, k)))
+        _spec(one_chip, (n_cols, n, kw), jnp.int32),
+        _spec(one_chip, (n_cols, n, kw), wdtype))
     assert "tpu_custom_call" in text
     assert " gather(" not in text
 
@@ -154,6 +175,46 @@ def test_single_shard_step_compiles(one_chip, compiles_kernels, impl):
         jax.eval_shape(lambda: sim.build(cfg)))
     text = _compiled_text(net.make_step_fn(cfg, impl=impl), params, state)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
+def test_tpu_row_major_is_the_compilers_layout(topo, dtype):
+    """``network.tpu_row_major`` says what the v5e's own default layout of
+    synapse-table shapes is: grids of 1 to 96 columns a side (whole lane
+    tiles of columns and not), column sizes on and off the 8-row tiling,
+    widths of N, K = 24, 248 and 1,028, and whole lanes."""
+    from jax.experimental.layout import Layout
+
+    from repro.core import network as net
+
+    dev = topo.devices[0]
+    for g, n, w in itertools.product(
+            (1, 2, 3, 4, 8, 16, 24, 32, 40, 48, 96), (20, 100, 136, 1240),
+            (None, 24, 248, 256, 1028, 1152)):
+        shape = (g * g, n, w or n)
+        layout = Layout.from_pjrt_layout(
+            dev.client.get_default_layout(jnp.dtype(dtype), shape, dev))
+        assert net.tpu_row_major(shape, jnp.dtype(dtype).itemsize) == (
+            layout.major_to_minor == (0, 1, 2)), shape
+
+
+@pytest.mark.parametrize("grid", [32, 40, 48])
+def test_static_window_copies_no_params(one_chip, compiles_kernels, grid):
+    """A static ``sim.run`` window of two steps on one chip, with the
+    bfloat16 tables the chip's build stores (lane-padded at 32x32 and
+    48x48, as they are at 40x40): no temporary or output of the program
+    is as large as the local weights, so nothing copies them."""
+    from repro.core import simulation as sim
+
+    cfg = dataclasses.replace(dpsnn.GRID_48_BF16, grid_h=grid, grid_w=grid)
+    params, state = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: sim.build(cfg)))
+    mem = sim.run.lower(cfg, params, state, 2,
+                        impl="pallas_fused").compile().memory_analysis()
+    w_bytes = params.w_local.size * params.w_local.dtype.itemsize
+    assert mem.temp_size_in_bytes < w_bytes / 8
+    assert mem.output_size_in_bytes < w_bytes / 8
 
 
 def test_distributed_step_compiles_on_2x2(mesh, compiles_kernels):
